@@ -47,8 +47,15 @@ def _make_party_data(n_parties, n_per_party, n_feat, n_classes, seed):
     return x, y.astype(np.int32), ex, ey.astype(np.int32)
 
 
-def bench_exchange(n_parties=10000, cycles=3, edges=32, seed=0,
-                   mlp_frac=0.2):
+def build_cohorts(n_parties=10000, cycles=3, seed=0, mlp_frac=0.2,
+                  mesh=None):
+    """The benchmark's market: ``(cohorts, eval_x, eval_y, traces)``.
+
+    LR parties and ``mlp_frac`` MLP parties (hidden 32), each with 64
+    samples of 16 features over 8 classes, batch 32, plus one Markov
+    availability trace per cohort.  ``mesh`` shards each cohort's party
+    axis (see :class:`PartyPopulation`).
+    """
     n_per_party, n_feat, n_classes = 64, 16, 8
     x, y, ex, ey = _make_party_data(n_parties, n_per_party, n_feat,
                                     n_classes, seed)
@@ -59,18 +66,26 @@ def bench_exchange(n_parties=10000, cycles=3, edges=32, seed=0,
         cohorts.append(PartyPopulation(
             make_lr(num_features=n_feat, num_classes=n_classes),
             x[:n_lr], y[:n_lr], task="exchange_bench", lr=0.1, batch_size=32,
-            seed=seed, party_ids=[f"lr{i}" for i in range(n_lr)],
+            seed=seed, party_ids=[f"lr{i}" for i in range(n_lr)], mesh=mesh,
         ))
     if n_mlp:
         cohorts.append(PartyPopulation(
             make_mlp(num_features=n_feat, num_classes=n_classes, hidden=32),
             x[n_lr:], y[n_lr:], task="exchange_bench", lr=0.1, batch_size=32,
             seed=seed + 1, party_ids=[f"mlp{i}" for i in range(n_mlp)],
+            mesh=mesh,
         ))
 
     traces = [markov_trace(pop.num_parties, horizon=max(cycles, 8),
                            seed=seed + 7 * k)
               for k, pop in enumerate(cohorts)]
+    return cohorts, ex, ey, traces
+
+
+def bench_exchange(n_parties=10000, cycles=3, edges=32, seed=0,
+                   mlp_frac=0.2):
+    cohorts, ex, ey, traces = build_cohorts(n_parties, cycles, seed,
+                                            mlp_frac)
 
     wall0 = time.perf_counter()
     marks = []  # (cycle, wall time at that cohort-cycle's completion)

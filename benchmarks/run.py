@@ -198,6 +198,9 @@ def main():
             raise SystemExit(2)
         _JSON_PATH = argv[i + 1]
         argv = argv[:i] + argv[i + 2:]
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     which = set(argv) or {"fig3", "figs456", "kernels", "traffic",
                           "continuum_scale", "exchange_scale",
                           "chaos_scale", "drift_scale", "hierarchy_scale",

@@ -48,36 +48,38 @@ def _kd_kernel(
 
     sl = s_ref[...].astype(jnp.float32)  # (bn, bv)
     tl = t_ref[...].astype(jnp.float32)
-    labels = lab_ref[...]  # (bn,)
+    labels = lab_ref[...]  # (bn, 1)
     cols = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, (block_n, block_v), 1)
     valid = cols < vocab
     sl = jnp.where(valid, sl, NEG_INF)
     tl = jnp.where(valid, tl, NEG_INF)
 
     # ---- student, T=1 (CE) ----
-    m_new = jnp.maximum(m_s1[...], jnp.max(sl, -1))
+    m_new = jnp.maximum(m_s1[...], jnp.max(sl, -1, keepdims=True))
     corr = jnp.exp(m_s1[...] - m_new)
-    l_s1[...] = l_s1[...] * corr + jnp.sum(jnp.exp(sl - m_new[:, None]), -1)
+    l_s1[...] = l_s1[...] * corr + jnp.sum(jnp.exp(sl - m_new), -1,
+                                           keepdims=True)
     m_s1[...] = m_new
-    is_gold = cols == labels[:, None]
-    gold[...] += jnp.sum(jnp.where(is_gold, sl, 0.0), -1)
+    gold[...] += jnp.sum(jnp.where(cols == labels, sl, 0.0), -1, keepdims=True)
 
     # ---- student at T (KL) ----
     sl_t = sl * inv_t
-    m_new = jnp.maximum(m_s[...], jnp.max(sl_t, -1))
+    m_new = jnp.maximum(m_s[...], jnp.max(sl_t, -1, keepdims=True))
     corr = jnp.exp(m_s[...] - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(jnp.exp(sl_t - m_new[:, None]), -1)
+    l_s[...] = l_s[...] * corr + jnp.sum(jnp.exp(sl_t - m_new), -1,
+                                         keepdims=True)
     m_s[...] = m_new
 
     # ---- teacher at T: weights + weighted sums of tl_t and sl_t ----
     tl_t = tl * inv_t
-    m_new = jnp.maximum(m_t[...], jnp.max(tl_t, -1))
+    m_new = jnp.maximum(m_t[...], jnp.max(tl_t, -1, keepdims=True))
     corr = jnp.exp(m_t[...] - m_new)
-    p = jnp.exp(tl_t - m_new[:, None])
+    p = jnp.exp(tl_t - m_new)
     p = jnp.where(valid, p, 0.0)
-    l_t[...] = l_t[...] * corr + jnp.sum(p, -1)
-    s_tt[...] = s_tt[...] * corr + jnp.sum(p * tl_t, -1)
-    s_ts[...] = s_ts[...] * corr + jnp.sum(p * jnp.where(valid, sl_t, 0.0), -1)
+    l_t[...] = l_t[...] * corr + jnp.sum(p, -1, keepdims=True)
+    s_tt[...] = s_tt[...] * corr + jnp.sum(p * tl_t, -1, keepdims=True)
+    s_ts[...] = s_ts[...] * corr + jnp.sum(
+        p * jnp.where(valid, sl_t, 0.0), -1, keepdims=True)
     m_t[...] = m_new
 
     @pl.when(vi == v_steps - 1)
@@ -106,7 +108,13 @@ def kd_loss(
     block_v=2048,
     interpret=False,
 ):
-    """Per-row fused distillation loss. (N,V),(N,V),(N,) -> (N,) f32."""
+    """Per-row fused distillation loss. (N,V),(N,V),(N,) -> (N,) f32.
+
+    Labels, output and the per-row accumulators are carried as ``(rows, 1)``
+    columns: Mosaic rejects 1-D row blocks (their layout differs from
+    XLA's), and once ``vmap`` adds a party axis a 1-D block's last two
+    dimensions would no longer be tile-aligned.
+    """
     N, V = student_logits.shape
     block_n = min(block_n, N)
     assert N % block_n == 0, (N, block_n)
@@ -126,16 +134,17 @@ def kd_loss(
     def scr(shape):
         return pltpu.VMEM(shape, jnp.float32)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, block_v), lambda ni, vi: (ni, vi)),
             pl.BlockSpec((block_n, block_v), lambda ni, vi: (ni, vi)),
-            pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
+            pl.BlockSpec((block_n, 1), lambda ni, vi: (ni, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda ni, vi: (ni,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
-        scratch_shapes=[scr((block_n,)) for _ in range(9)],
+        out_specs=pl.BlockSpec((block_n, 1), lambda ni, vi: (ni, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
+        scratch_shapes=[scr((block_n, 1)) for _ in range(9)],
         interpret=interpret,
-    )(student_logits, teacher_logits, labels)
+    )(student_logits, teacher_logits, labels.reshape(N, 1))
+    return out[:, 0]

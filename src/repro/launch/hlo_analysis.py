@@ -124,12 +124,8 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()``: newer jax returns a dict,
-    jax 0.4.x wraps the per-device dict in a single-element list."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``Compiled.cost_analysis()`` as a plain dict (empty when absent)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def cost_summary(compiled) -> Dict[str, float]:

@@ -8,6 +8,7 @@ init; smoke tests and benches see the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 TARGET = {
     "name": "tpu-v5e",
@@ -18,15 +19,22 @@ TARGET = {
 }
 
 
+def _auto_mesh(shape, axes):
+    # the program's shardings are annotations for the compiler to propagate
+    # (GSPMD), so every axis is ``Auto``; ``Explicit`` axes would make each
+    # op's output sharding part of its type
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh with the production axis names (CPU smoke/integration)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_party_mesh(num_devices: int | None = None):
@@ -38,4 +46,4 @@ def make_party_mesh(num_devices: int | None = None):
     whose sharded cycles are bit-identical to the unsharded path.
     """
     n = num_devices if num_devices is not None else jax.local_device_count()
-    return jax.make_mesh((n,), ("party",))
+    return _auto_mesh((n,), ("party",))

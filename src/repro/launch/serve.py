@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.runtime.serving import SlotQueue
 
@@ -66,6 +67,41 @@ def run_slot(cfg, prefill_fn, serve_fn, params, prompts, bucket, max_new):
     return np.stack(outs, axis=1), logits, t_prefill, t_decode
 
 
+def build_engine(cfg, bucket, max_new):
+    """``(model, prefill_fn, serve_fn)``: the jitted prefill and decode steps.
+
+    The cache is sized for the full generation so no decode write clamps.
+    """
+    prefill_fn, model = make_prefill_step(cfg, cache_len=bucket + max_new)
+    serve_fn, _ = make_serve_step(cfg)
+    return (model, jax.jit(prefill_fn),
+            jax.jit(serve_fn, donate_argnums=(1,)))
+
+
+def serve_prompts(cfg, prefill_fn, serve_fn, params, prompts, *, bucket,
+                  max_new, max_batch):
+    """Batch ``prompts`` through the :class:`SlotQueue` and decode each slot.
+
+    Returns ``(gen, slots)``: ``gen`` holds the ``(len(prompts), max_new)``
+    generated ids at each request's index; ``slots`` lists one
+    ``(idxs, logits, t_prefill, t_decode)`` per drained slot, ``logits``
+    being the slot's last decode step.
+    """
+    queue = SlotQueue(buckets=(bucket,), max_batch=max_batch)
+    for i, p in enumerate(prompts):
+        queue.add(cfg.name, len(p), i)
+    gen = np.zeros((len(prompts), max_new), np.int32)
+    slots = []
+    while len(queue):
+        idxs = queue.drain(cfg.name, bucket)
+        rows, logits, tp, td = run_slot(cfg, prefill_fn, serve_fn, params,
+                                        [prompts[i] for i in idxs],
+                                        bucket, max_new)
+        gen[np.asarray(idxs)] = rows
+        slots.append((idxs, logits, tp, td))
+    return gen, slots
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_1_5b")
@@ -76,41 +112,25 @@ def main(argv=None):
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    # cache sized for the full generation so no decode write ever clamps
-    prefill_fn, model = make_prefill_step(cfg,
-                                          cache_len=args.bucket + args.max_new)
-    serve_fn, _ = make_serve_step(cfg)
-    prefill_fn = jax.jit(prefill_fn)
-    serve_fn = jax.jit(serve_fn, donate_argnums=(1,))
-
+    model, prefill_fn, serve_fn = build_engine(cfg, args.bucket, args.max_new)
     params = model.init(jax.random.PRNGKey(args.seed))
     prompts = make_requests(cfg, args.requests, args.seed)
 
-    queue = SlotQueue(buckets=(args.bucket,), max_batch=args.max_batch)
-    for i, p in enumerate(prompts):
-        queue.add(args.arch, len(p), i)
-
-    gen = np.zeros((args.requests, args.max_new), np.int32)
-    t_prefill = t_decode = 0.0
-    n_slots = 0
-    while len(queue):
-        idxs = queue.drain(args.arch, args.bucket)
-        rows, logits, tp, td = run_slot(cfg, prefill_fn, serve_fn, params,
-                                        [prompts[i] for i in idxs],
-                                        args.bucket, args.max_new)
+    gen, slots = serve_prompts(cfg, prefill_fn, serve_fn, params, prompts,
+                               bucket=args.bucket, max_new=args.max_new,
+                               max_batch=args.max_batch)
+    for _, logits, _, _ in slots:
         assert np.isfinite(np.asarray(logits, np.float32)).all()
-        gen[np.asarray(idxs)] = rows
-        t_prefill += tp
-        t_decode += td
-        n_slots += 1
+    t_prefill = sum(s[2] for s in slots)
+    t_decode = sum(s[3] for s in slots)
 
-    assert gen.shape == (args.requests, args.max_new)
     for i, p in enumerate(prompts):
         print(f"req{i}: prompt_len={len(p)} -> {gen[i, :8].tolist()}...")
     tps = args.requests * args.max_new / max(t_decode, 1e-9)
-    print(f"{n_slots} slot(s)   prefill {t_prefill:.2f}s   "
+    print(f"{len(slots)} slot(s)   prefill {t_prefill:.2f}s   "
           f"decode {t_decode:.2f}s ({tps:.1f} tok/s batch-aggregate)")
     return 0
 

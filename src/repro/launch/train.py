@@ -20,6 +20,7 @@ import numpy as np
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import synthetic_token_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models.config import ShapeConfig
 
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = build_cfg(args)
     shape = ShapeConfig("cli", args.seq, args.batch, "train",

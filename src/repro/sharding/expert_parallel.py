@@ -27,12 +27,6 @@ import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 
-# partial-auto shard_map (manual data axis, auto model axis) needs
-# ``jax.shard_map(..., axis_names=...)``; jax 0.4.x's experimental
-# shard_map raises NotImplementedError for this mode, so there is no
-# fallback — callers gate on this flag (see tests/test_moe.py).
-HAS_PARTIAL_AUTO_SHARD_MAP = hasattr(jax, "shard_map")
-
 
 def _local_ranks(flat_e, num_experts):
     nk = flat_e.shape[0]

@@ -251,14 +251,6 @@ def replicated(mesh: Mesh):
 # axis that shards data-parallel across it (ISSUE 6 / ROADMAP item 1).
 PARTY_AXIS = "party"
 
-try:  # jax >= 0.4.35 ships shard_map under jax.experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    HAS_SHARD_MAP = True
-except ImportError:  # pragma: no cover - ancient jax
-    _shard_map = None
-    HAS_SHARD_MAP = False
-
 
 def party_mesh_size(mesh: Optional[Mesh]) -> int:
     """Number of shards along the party axis (1 without a mesh)."""
@@ -277,19 +269,14 @@ def party_shard_map(fn, mesh: Optional[Mesh], *, in_specs, out_specs):
     """Wrap ``fn`` in ``shard_map`` over the party mesh; identity without one.
 
     ``in_specs``/``out_specs`` may be PartitionSpec pytree prefixes, as
-    usual for ``shard_map``.  ``check_rep=False`` because the population
+    usual for ``shard_map``.  ``check_vma=False`` because the population
     cycle is a pure per-shard map with no collectives.  Callers that jit
     the result keep a single code path whether or not a mesh exists.
     """
     if mesh is None:
         return fn
-    if not HAS_SHARD_MAP:  # pragma: no cover - ancient jax
-        raise RuntimeError(
-            "party-axis sharding requires jax.experimental.shard_map; "
-            "run without a mesh on this jax version"
-        )
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -298,10 +285,7 @@ def party_shard_map(fn, mesh: Optional[Mesh], *, in_specs, out_specs):
 
 
 def _context_axes():
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - very old jax
-        return ()
+    am = jax.sharding.get_abstract_mesh()
     return tuple(am.axis_names) if am is not None else ()
 
 

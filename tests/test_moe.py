@@ -2,9 +2,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.common.types import init_params
+from repro.launch.mesh import make_host_mesh
 from repro.models.config import ModelConfig
 from repro.models.moe import (
     _expert_ranks,
@@ -108,23 +108,13 @@ def test_grad_flows_through_dispatch():
     assert float(jnp.sum(jnp.abs(grads["router"]))) > 0
 
 
-def _requires_partial_auto_shard_map():
-    from repro.sharding.expert_parallel import HAS_PARTIAL_AUTO_SHARD_MAP
-
-    return pytest.mark.skipif(
-        not HAS_PARTIAL_AUTO_SHARD_MAP,
-        reason="partial-auto shard_map needs jax.shard_map (jax >= 0.5)",
-    )
-
-
-@_requires_partial_auto_shard_map()
 def test_expert_parallel_matches_dense_single_device():
     """shard_map all-to-all schedule == grouped-dispatch path (1-device mesh)."""
     from repro.sharding.expert_parallel import moe_apply_expert_parallel
 
     cfg = _cfg()
     params, x = _setup(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     y1, l1 = moe_apply_dense(params, cfg, x, capacity_factor=4.0, group_size=32)
     y2, l2 = moe_apply_expert_parallel(params, cfg, x, mesh=mesh,
                                        capacity_factor=4.0)
@@ -132,12 +122,11 @@ def test_expert_parallel_matches_dense_single_device():
     np.testing.assert_allclose(float(l1["moe_aux"]), float(l2["moe_aux"]), rtol=1e-5)
 
 
-@_requires_partial_auto_shard_map()
 def test_expert_parallel_with_shared_expert():
     from repro.sharding.expert_parallel import moe_apply_expert_parallel
 
     cfg = _cfg(num_shared_experts=1, experts_per_token=1)
     params, x = _setup(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     y, _ = moe_apply_expert_parallel(params, cfg, x, mesh=mesh)
     assert y.shape == x.shape and bool(jnp.all(jnp.isfinite(y)))

@@ -15,7 +15,7 @@ from repro.core.vault import ModelCard
 from repro.launch.mesh import make_party_mesh
 from repro.models.small import make_lr, make_mlp
 from repro.runtime.population import CohortState, PartyPopulation, stack_teachers
-from repro.sharding.rules import HAS_SHARD_MAP, party_mesh_size
+from repro.sharding.rules import party_mesh_size
 
 N_PARTIES, N_PER, N_FEAT, N_CLASSES = 6, 64, 8, 4
 
@@ -128,8 +128,6 @@ def test_all_party_params_matches_per_party_export():
 
 
 def test_single_device_mesh_is_bit_identical():
-    if not HAS_SHARD_MAP:
-        pytest.skip("shard_map unavailable in this jax build")
     meshed = _pop(fused=True, mesh=make_party_mesh())
     plain = _pop(fused=True, mesh=None)
     lm = meshed.train_epochs(2)
@@ -147,13 +145,10 @@ def test_single_device_mesh_is_bit_identical():
 
 def test_party_mesh_capability_gate():
     assert party_mesh_size(None) == 1
-    if HAS_SHARD_MAP:
-        assert party_mesh_size(make_party_mesh()) == jax.local_device_count()
+    assert party_mesh_size(make_party_mesh()) == jax.local_device_count()
 
 
 def test_mesh_pads_party_axis_to_device_multiple():
-    if not HAS_SHARD_MAP:
-        pytest.skip("shard_map unavailable in this jax build")
     # 6 parties on a 1-device mesh need no padding; the padded count is
     # always a device multiple and public views never include pad rows
     f = _pop(fused=True, mesh=make_party_mesh())
@@ -192,8 +187,6 @@ print("OK")
 
 @pytest.mark.slow
 def test_multi_device_mesh_matches_single_device():
-    if not HAS_SHARD_MAP:
-        pytest.skip("shard_map unavailable in this jax build")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")

@@ -51,13 +51,13 @@ def test_pspec_moe_rules():
 
 
 def test_evenly_guard():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     # 1-sized axes divide everything
     assert evenly(P("model"), (7,), mesh) == P("model")
 
 
 def test_opt_state_pspec_adds_data_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     ps = opt_state_pspec(P(None, "model"), (64, 32), mesh)
     assert ps == P("data", "model")
     # already data-sharded params stay unchanged
@@ -66,7 +66,7 @@ def test_opt_state_pspec_adds_data_axis():
 
 
 def test_cache_pspec_heuristics():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     cfg = get_smoke_config("qwen2_1_5b")
     # kv-cache-like leaf: (layers, B, T, KV, hd)
     tree = {"k": jax.ShapeDtypeStruct((2, 16, 64, cfg.num_kv_heads, 32), jnp.bfloat16)}
