@@ -5,8 +5,14 @@ Layouts:
   activations  (B, S, D)
   q            (B, S, H, hd)
   k, v         (B, S, KV, hd)
-  cache.k/v    (B, T, KV, hd)   T = seq_len (full) or window (sliding)
+  cache.k/v    (B, KV, T, hd)   T = seq_len (full) or window (sliding);
+                                head-major, so a layer's slice is what the
+                                decode attention reads, with no relayout
   cache.pos    (B, T) int32     absolute position per slot, -1 = empty
+
+Decode reads each layer's cache where it lies and returns only the new
+token's K/V; ``write_cache`` puts those into the stacked caches after the
+layer scan, in place when the cache is donated.
 """
 from __future__ import annotations
 
@@ -213,74 +219,120 @@ def attend_cross_cached(params, cfg: ModelConfig, x, xk, xv):
 # ---------------------------------------------------------------------------
 
 
+def _cache_len(cfg: ModelConfig, seq_len: int) -> int:
+    return seq_len if cfg.sliding_window is None else min(cfg.sliding_window, seq_len)
+
+
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
     """Cache for one attention layer. T = window size when sliding."""
-    T = seq_len if cfg.sliding_window is None else min(cfg.sliding_window, seq_len)
+    T = _cache_len(cfg, seq_len)
     hd = cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((batch, T, cfg.num_kv_heads, hd), dtype),
-        "v": jnp.zeros((batch, T, cfg.num_kv_heads, hd), dtype),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, T, hd), dtype),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, T, hd), dtype),
         "pos": jnp.full((batch, T), -1, jnp.int32),
     }
 
 
 def cache_abstract(cfg: ModelConfig, batch: int, seq_len: int, dtype):
-    T = seq_len if cfg.sliding_window is None else min(cfg.sliding_window, seq_len)
+    T = _cache_len(cfg, seq_len)
     hd = cfg.resolved_head_dim
     return {
-        "k": jax.ShapeDtypeStruct((batch, T, cfg.num_kv_heads, hd), dtype),
-        "v": jax.ShapeDtypeStruct((batch, T, cfg.num_kv_heads, hd), dtype),
+        "k": jax.ShapeDtypeStruct((batch, cfg.num_kv_heads, T, hd), dtype),
+        "v": jax.ShapeDtypeStruct((batch, cfg.num_kv_heads, T, hd), dtype),
         "pos": jax.ShapeDtypeStruct((batch, T), jnp.int32),
     }
 
 
-def decode_step(params, cfg: ModelConfig, cache, x, pos):
-    """One-token decode. x: (B,1,D); pos: scalar int32 absolute position.
+def cache_slot(cfg: ModelConfig, pos, T: int):
+    """The slot position ``pos`` takes in a cache of ``T`` slots: a ring
+    index under a sliding window, else ``pos`` clamped to the last slot
+    as ``dynamic_update_slice`` clamps it."""
+    pos = jnp.asarray(pos, jnp.int32)
+    if cfg.sliding_window is None:
+        return jnp.minimum(pos, T - 1)
+    return pos % T
 
-    Returns (out (B,1,D), new_cache).
+
+def decode_step(params, cfg: ModelConfig, cache, x, pos):
+    """One-token decode against one layer's cache, which it only reads.
+
+    x: (B,1,D); pos: scalar int32 absolute position.  The query attends to
+    the cache's valid entries and to the token's own K/V, joined in one
+    f32 softmax.  The slot the token will take is left out: in a ring
+    smaller than the window it still holds position ``pos - T``.
+
+    Returns (out (B,1,D), {"k", "v"} each (B,KV,1,hd)): the token's
+    entries, for ``write_cache``.
     """
     q, k, v = _project_qkv(params, cfg, x)
     positions = jnp.full((x.shape[0], 1), pos, jnp.int32)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
+    B, _, H, hd = q.shape
+    KV, T = cache["k"].shape[1], cache["k"].shape[2]
+    qg = q.reshape(B, KV, H // KV, hd)
+    k = k.reshape(B, KV, 1, hd)
+    v = v.reshape(B, KV, 1, hd)
 
-    T = cache["k"].shape[1]
-    if cfg.sliding_window is None:
-        slot = jnp.asarray(pos, jnp.int32)
-    else:
-        slot = jnp.asarray(pos % T, jnp.int32)
-    ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-    cpos = jax.lax.dynamic_update_slice(
-        cache["pos"], positions.astype(jnp.int32), (0, slot)
-    )
-
-    scores = _gqa_scores(q, ck)  # (B,KV,G,1,T)
-    valid = (cpos >= 0) & (cpos <= pos)
+    scale = jnp.sqrt(hd).astype(q.dtype)
+    s_cache = jnp.einsum("bkgd,bktd->bkgt", qg, cache["k"]) / scale
+    s_self = jnp.einsum("bkgd,bkud->bkgu", qg, k) / scale
+    cpos = cache["pos"]
+    valid = ((cpos >= 0) & (cpos < pos)
+             & (jnp.arange(T) != cache_slot(cfg, pos, T)))
     if cfg.sliding_window is not None:
         valid = valid & (pos - cpos < cfg.sliding_window)
-    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-    weights = _softmax(scores).astype(x.dtype)
-    out = _gqa_out(weights, cv, x.dtype)
+    s_cache = jnp.where(valid[:, None, None, :], s_cache, NEG_INF)
+    weights = _softmax(jnp.concatenate([s_cache, s_self], axis=-1))
+    weights = weights.astype(x.dtype)
+    out = (jnp.einsum("bkgt,bktd->bkgd", weights[..., :T], cache["v"],
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgu,bkud->bkgd", weights[..., T:], v,
+                        preferred_element_type=jnp.float32))
+    out = out.reshape(B, 1, H * hd).astype(x.dtype)
     out = jnp.einsum("bsh,hd->bsd", out, params["wo"])
-    return out, {"k": ck, "v": cv, "pos": cpos}
+    return out, {"k": k, "v": v}
+
+
+def write_cache(cfg: ModelConfig, cache, new, pos):
+    """Write one token's K/V into stacked caches at ``pos``'s slot.
+
+    cache: "k", "v" (..., B, KV, T, hd) and "pos" (..., B, T); new: "k",
+    "v" (..., B, KV, 1, hd), as the layer scan stacks ``decode_step``'s.
+    One ``dynamic_update_slice`` per array, in place on a donated cache.
+    """
+    ck, cpos = cache["k"], cache["pos"]
+    slot = cache_slot(cfg, pos, ck.shape[-2])
+    at = (0,) * (ck.ndim - 2) + (slot, 0)
+    token_pos = jnp.full(cpos.shape[:-1] + (1,), pos, jnp.int32)
+    return {
+        "k": jax.lax.dynamic_update_slice(ck, new["k"].astype(ck.dtype), at),
+        "v": jax.lax.dynamic_update_slice(
+            cache["v"], new["v"].astype(cache["v"].dtype), at),
+        "pos": jax.lax.dynamic_update_slice(
+            cpos, token_pos, (0,) * (cpos.ndim - 1) + (slot,)),
+    }
 
 
 def fill_cache_from_prefill(cfg: ModelConfig, kv, positions, seq_len: int):
     """Build a decode cache from prefill K/V (already roped).
 
-    kv: (k, v) each (B,S,KV,hd); keeps the trailing ``window`` slots when
-    sliding-window attention is active.
+    kv: (k, v) each (B,S,KV,hd), stored head-major.  When sliding-window
+    attention keeps only the trailing ``T`` positions, position ``p`` lands
+    in ring slot ``p % T``, the slot its decode successors evict.
     """
-    k, v = kv
-    B, S = k.shape[0], k.shape[1]
-    T = seq_len if cfg.sliding_window is None else min(cfg.sliding_window, seq_len)
+    k, v = (jnp.swapaxes(a, 1, 2) for a in kv)  # (B,KV,S,hd)
+    S = k.shape[2]
+    T = _cache_len(cfg, seq_len)
     if S >= T:
-        k_t, v_t = k[:, S - T :], v[:, S - T :]
-        pos_t = positions[:, S - T :]
+        shift = S % T
+        k_t = jnp.roll(k[:, :, S - T:], shift, axis=2)
+        v_t = jnp.roll(v[:, :, S - T:], shift, axis=2)
+        pos_t = jnp.roll(positions[:, S - T:], shift, axis=1)
     else:
         pad = T - S
-        k_t = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_t = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_t = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_t = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
         pos_t = jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)
     return {"k": k_t, "v": v_t, "pos": pos_t.astype(jnp.int32)}

@@ -168,13 +168,15 @@ def encdec_prefill(params, cfg: ModelConfig, batch, cache_len=None):
 
 
 def encdec_decode(params, cfg: ModelConfig, cache, batch):
+    """One-token decode; the layer scan only reads the self-attention cache
+    and the cross-attention ``xk``/``xv``, and the token's K/V go in after."""
     dec = params["decoder"]
     x = embed(dec["embed"], batch["token"]).astype(jnp.dtype(cfg.dtype))
     pos = cache["pos"]
 
     def body(x, scanned):
         bp, bc = scanned
-        h, new_kv = attn.decode_step(
+        h, token_kv = attn.decode_step(
             bp["attn"], cfg, bc["kv"], rmsnorm(bp["ln1"], x), pos
         )
         x = x + h
@@ -183,12 +185,14 @@ def encdec_decode(params, cfg: ModelConfig, cache, batch):
         )
         x = x + h
         x = x + mlp_apply(cfg.mlp_type, bp["mlp"], rmsnorm(bp["ln2"], x))
-        return x, {"kv": new_kv, "xk": bc["xk"], "xv": bc["xv"]}
+        return x, token_kv
 
-    x, new_blocks = maybe_scan(body, x, (dec["blocks"], cache["blocks"]))
+    x, token_kv = maybe_scan(body, x, (dec["blocks"], cache["blocks"]))
+    blocks = {**cache["blocks"],
+              "kv": attn.write_cache(cfg, cache["blocks"]["kv"], token_kv, pos)}
     x = rmsnorm(dec["final_norm"], x)
     logits = unembed(dec["embed"], x)
-    return logits, {"blocks": new_blocks, "pos": pos + 1}
+    return logits, {"blocks": blocks, "pos": pos + 1}
 
 
 def build_encdec_model(cfg: ModelConfig) -> Model:

@@ -328,9 +328,13 @@ def decoder_prefill(params, cfg: ModelConfig, batch, cache_len=None):
 
 
 def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
-    """Single-token decode through one super-block."""
+    """Single-token decode through one super-block.
+
+    Returns (x, new): each attention block's token K/V under "kv", and
+    the recurrent states, which a step replaces whole.
+    """
     if cfg.family in ("dense", "moe", "vlm"):
-        h, new_kv = attn.decode_step(
+        h, token_kv = attn.decode_step(
             p["attn"], cfg, cache["kv"], rmsnorm(p["ln1"], x), pos
         )
         x = x + h
@@ -338,7 +342,7 @@ def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
             y, _ = moe_lib.moe_apply(p["moe"], cfg, rmsnorm(p["ln2"], x))
         else:
             y = mlp_apply(cfg.mlp_type, p["mlp"], rmsnorm(p["ln2"], x))
-        return x + y, {"kv": new_kv}
+        return x + y, {"kv": token_kv}
 
     if cfg.family == "hybrid":
 
@@ -348,12 +352,12 @@ def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
             return carry + h, new_c
 
         x, new_ssm = maybe_scan(mamba_body, x, (p["mamba"], cache["ssm"]))
-        h, new_kv = attn.decode_step(
+        h, token_kv = attn.decode_step(
             shared["attn"], cfg, cache["kv"], rmsnorm(shared["ln1"], x), pos
         )
         x = x + h
         y = mlp_apply(cfg.mlp_type, shared["mlp"], rmsnorm(shared["ln2"], x))
-        return x + y, {"kv": new_kv, "ssm": new_ssm}
+        return x + y, {"kv": token_kv, "ssm": new_ssm}
 
     if cfg.family == "ssm":
 
@@ -370,21 +374,25 @@ def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
 
 
 def decoder_decode(params, cfg: ModelConfig, cache, batch):
-    """One-token decode. batch: {"token": (B,1)}. Returns (logits, cache)."""
+    """One-token decode. batch: {"token": (B,1)}. Returns (logits, cache).
+
+    The layer scan reads the cache; the token's K/V go in after it.
+    """
     x = embed(params["embed"], batch["token"]).astype(jnp.dtype(cfg.dtype))
     pos = cache["pos"]
     shared = params.get("shared_attn")
 
-    def body(carry, scanned):
+    def body(x, scanned):
         bp, bc = scanned
-        x = carry
-        x, new_c = _super_decode(cfg, shared, bp, bc, x, pos)
-        return x, new_c
+        return _super_decode(cfg, shared, bp, bc, x, pos)
 
-    x, new_blocks = maybe_scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new = maybe_scan(body, x, (params["blocks"], cache["blocks"]))
+    blocks = {**cache["blocks"], **new}
+    if "kv" in new:
+        blocks["kv"] = attn.write_cache(cfg, cache["blocks"]["kv"], new["kv"], pos)
     x = rmsnorm(params["final_norm"], x)
     logits = unembed(params["embed"], x)
-    return logits, {"blocks": new_blocks, "pos": pos + 1}
+    return logits, {"blocks": blocks, "pos": pos + 1}
 
 
 # ---------------------------------------------------------------------------

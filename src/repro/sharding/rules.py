@@ -184,6 +184,10 @@ def cache_pspecs(cache_tree, cfg, mesh: Mesh):
     - a dim equal to the (global) batch size shards over data when divisible;
     - a KV/SSM/xLSTM heads-like dim shards over model (GSPMD pads uneven);
     - with batch=1 (long_500k) the cache *time* dim shards over data instead.
+
+    The batch dim sits at index 1, or at 2 under a second stacked dim.
+    Behind a batch of 1 at index 1, a heads-like dim at index 2 is the
+    head-major KV cache's heads, not the batch.
     """
     sizes = _mesh_axis_sizes(mesh)
     data_ax = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
@@ -208,7 +212,9 @@ def cache_pspecs(cache_tree, cfg, mesh: Mesh):
         for i, d in enumerate(shape):
             if i == 0 and len(shape) > 1:
                 continue  # leading stacked-layers dim stays replicated
-            if not batch_done and d != 1 and d % data_size == 0 and i <= 2:
+            kv_heads_behind_b1 = i == 2 and shape[1] == 1 and d in head_like
+            if (not batch_done and d != 1 and d % data_size == 0 and i <= 2
+                    and not kv_heads_behind_b1):
                 entries[i] = data_name
                 batch_done = True
                 continue

@@ -4,8 +4,12 @@ What the TPU compiler refuses (a block layout Mosaic rejects, a program
 larger than the chip's memory) fails here instead of on the chip.  The
 ``kd_loss`` kernel compiles at the distill cycle's shape under ``vmap``
 and at a 151936-word vocabulary; the full-width Qwen2-1.5B prefill and
-decode steps compile within one chip's 16 GB.
+decode steps compile within one chip's 16 GB, and the decode step
+updates its donated KV cache in place.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -80,3 +84,44 @@ def test_qwen2_1_5b_serving_step_fits_one_v5e(one_chip, kind):
         args = (params, cache, _on(one_chip, {"token": token}))
     compiled = jax.jit(step).lower(*args).compile()
     assert cost_summary(compiled)["peak_bytes"] < V5E_HBM_BYTES
+
+
+def _cache_sized_copies(hlo: str, layers: int, batch: int, length: int):
+    """Instructions of ``hlo`` that copy, or fuse into, an array of a
+    stacked cache's size: ``[layers, batch, ...]`` with ``length`` in it."""
+    out = []
+    for m in re.finditer(r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo):
+        name, dims, op = m.group(1), m.group(2), m.group(3)
+        dims = tuple(int(d) for d in dims.split(","))
+        if (dims[:2] == (layers, batch) and length in dims[2:]
+                and (op.startswith("copy") or op == "fusion")):
+            out.append(f"{name} {op} {dims}")
+    return out
+
+
+@pytest.mark.parametrize("batch,length", [
+    (16, 2048 + 16),   # the docs cell: 16 prompts of 2,048, 16 new
+    (16, 1024 + 128),  # the chat cell's largest slot: 1,024 + 128 new
+])
+def test_qwen2_1_5b_decode_updates_the_donated_cache_in_place(
+        one_chip, batch, length):
+    """The decode step reads the donated cache where it lies and writes the
+    token's K/V into it: no temp buffer of a layer's cache size, the whole
+    cache aliased to the output, and no copy of a stacked cache array."""
+    cfg = get_config("qwen2_1_5b")
+    step, model = make_serve_step(cfg)
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = model.cache_abstract(batch, length)
+    token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, _on(one_chip, cache), _on(one_chip, {"token": token})).compile()
+    mem = compiled.memory_analysis()
+    def nbytes(s):
+        return math.prod(s.shape) * s.dtype.itemsize
+
+    layer_k = nbytes(cache["blocks"]["kv"]["k"]) // cfg.num_layers
+    cache_bytes = sum(map(nbytes, jax.tree_util.tree_leaves(cache)))
+    assert mem.temp_size_in_bytes < layer_k
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert _cache_sized_copies(compiled.as_text(), cfg.num_layers, batch,
+                               length) == []

@@ -85,6 +85,24 @@ def test_decode_matches_prefill_continuation():
     assert np.argmax(a) == np.argmax(b)
 
 
+def test_sliding_window_decode_after_long_prompt_matches_forward():
+    """A prompt longer than the window: the prefill keeps its last ``w``
+    positions in the ring slots their decode successors evict, so each
+    decoded token attends to the same ``w`` predecessors as the forward."""
+    cfg = get_smoke_config("qwen2_1_5b").replace(dtype="float32",
+                                                 sliding_window=4)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 18), 0, cfg.vocab_size)
+    full, _ = model.forward(params, {"tokens": toks})
+    _, _, cache = model.prefill(params, {"tokens": toks[:, :15]}, cache_len=18)
+    for t in range(15, 18):
+        dec, cache = model.decode(params, cache, {"token": toks[:, t:t + 1]})
+        np.testing.assert_allclose(np.asarray(dec[:, 0], np.float32),
+                                   np.asarray(full[:, t], np.float32),
+                                   rtol=2e-4, atol=2e-4, err_msg=f"token {t}")
+
+
 def test_sliding_window_restricts_context():
     """With window w, token attends to at most w predecessors."""
     cfg = get_smoke_config("qwen2_1_5b").replace(sliding_window=4, num_layers=2)

@@ -76,6 +76,15 @@ def test_cache_pspec_heuristics():
     tree = {"k": jax.ShapeDtypeStruct((2, 1, 64, cfg.num_kv_heads, 32), jnp.bfloat16)}
     sh = cache_pspecs(tree, cfg, mesh)
     assert sh["k"].spec[2] == "data"
+    # head-major kv cache (layers, B, KV, T, hd), as attention stores it:
+    # batch to data, KV heads to model; at batch 1, T to data
+    kv = cfg.num_kv_heads
+    tree = {"k": jax.ShapeDtypeStruct((2, 16, kv, 64, 32), jnp.bfloat16)}
+    sh = cache_pspecs(tree, cfg, mesh)
+    assert sh["k"].spec == P(None, "data", "model", None, None)
+    tree = {"k": jax.ShapeDtypeStruct((2, 1, kv, 64, 32), jnp.bfloat16)}
+    sh = cache_pspecs(tree, cfg, mesh)
+    assert sh["k"].spec == P(None, None, "model", "data", None)
 
 
 @pytest.mark.parametrize("arch", ["qwen2_1_5b", "qwen3_moe_235b_a22b", "zamba2_2_7b",
@@ -100,10 +109,10 @@ def test_host_mesh_lowering_long_context():
     shape = ShapeConfig("long_500k", 2048, 1, "decode")
     step, args = input_specs(cfg, shape, mesh)
     compiled = jax.jit(step).lower(*args).compile()
-    # the cache is windowed, not full-length
+    # the cache is windowed, not full-length: k is (layers, B, KV, T, hd)
     cache_arg = args[1]
-    k_leaf = jax.tree_util.tree_leaves(cache_arg)[0]
-    assert k_leaf.shape[2] <= 2048
+    k_leaf = cache_arg["blocks"]["kv"]["k"]
+    assert k_leaf.shape[3] <= 2048
 
 
 def test_distill_step_host_lowering():
