@@ -17,6 +17,7 @@ from __future__ import annotations
 import functools
 import gc
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -25,7 +26,9 @@ import flops
 import harness
 from harness import Check, Outcome, log
 
-REF = harness.load_module(harness.BENCH / "refs" / "market.py")
+# the benchmark this driver belongs to: the reference the config names
+# lies beside it
+BENCH = Path(__file__).resolve().parents[1]
 
 
 def party_data(cfg: dict, seed: int):
@@ -238,9 +241,10 @@ def run(ctx) -> Outcome:
 
 def check(cfg, limits, inputs, bought, got, got_loss, *, lowp=None):
     """Every party's model after the cycle, and each cohort's reported
-    distill loss, against the reference's replay."""
+    distill loss, against the replay of the reference the config names."""
+    ref_module = harness.load_module(BENCH / cfg["reference"])
     replay = functools.partial(
-        REF.replay, inputs, bought, lr=cfg["learning_rate"],
+        ref_module.replay, inputs, bought, lr=cfg["learning_rate"],
         batch=cfg["batch_size"], alpha=cfg["exchange"]["alpha"],
         temperature=cfg["exchange"]["temperature"])
     ref, ref_loss = replay()

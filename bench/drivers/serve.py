@@ -1,6 +1,11 @@
 """Serve a decoder through ``repro.launch.serve.serve_prompts`` under a
 traffic mix, and check what it served against the plain reference.
 
+The config file picks both ends of the comparison: its ``model_type``
+names the mapping to the program's ``ModelConfig``
+(``families/<model_type>.py``), and its ``reference`` names the plain
+reference (``refs/<file>.py``).  Nothing here knows a model family.
+
 The traffic file sets the loop:
 
 * ``"loop": "open"``: independent users.  ``rate_per_s`` times the window
@@ -23,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -33,29 +39,25 @@ import harness
 import weights
 from harness import Check, Outcome, log, percentile
 
-REF = harness.load_module(harness.BENCH / "refs" / "dense_decoder.py")
+# the benchmark this driver belongs to: its families and references lie
+# beside it, so a copy of the benchmark runs its own
+BENCH = Path(__file__).resolve().parents[1]
 
 
 def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a Hugging Face Qwen2 config."""
-    from repro.models.config import ModelConfig
+    """The program's ``ModelConfig`` for a published config, by the mapping
+    of its family, ``families/<model_type>.py``."""
+    path = BENCH / "families" / f"{cfg['model_type']}.py"
+    if not path.exists():
+        raise SystemExit(
+            f"bench: no mapping for model_type {cfg['model_type']!r}: add "
+            f"{path.relative_to(BENCH.parent)} with program_config(cfg)")
+    return harness.load_module(path).program_config(cfg)
 
-    if cfg["model_type"] != "qwen2" or not cfg["tie_word_embeddings"]:
-        raise ValueError("the serving driver runs tied-embedding Qwen2 "
-                         f"decoders; got {cfg['model_type']}")
-    if cfg["rms_norm_eps"] != 1e-6 or cfg["hidden_act"] != "silu":
-        raise ValueError("the program's decoder fixes rmsnorm eps 1e-6 "
-                         "and SwiGLU")
-    d, heads = cfg["hidden_size"], cfg["num_attention_heads"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=d, num_heads=heads,
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or d // heads,
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        mlp_type="swiglu", qkv_bias=True, rope_theta=cfg["rope_theta"],
-        dtype=cfg["torch_dtype"],
-    )
+
+def reference(cfg: dict):
+    """The plain reference the config names under ``reference``."""
+    return harness.load_module(BENCH / cfg["reference"])
 
 
 def program_params(model, seed: int, dtype):
@@ -275,13 +277,14 @@ def run(ctx) -> Outcome:
 
 def check(cfg, tr, seed, prompts, served, *, lowp=None):
     """Compare a sample of served requests, drawn from the seed, with the
-    reference: the widest gap by which a served token's reference logit
-    lies below the reference's best at its position."""
+    config's reference: the widest gap by which a served token's
+    reference logit lies below the reference's best at its position."""
     rng = harness.host_rng(seed, "check")
     n = min(tr["check_requests"], len(prompts))
     pick = np.sort(rng.choice(len(prompts), n, replace=False))
-    gaps = REF.served_gaps(cfg, seed, np.stack([prompts[i] for i in pick]),
-                           served[pick], lowp=lowp)
+    gaps = reference(cfg).served_gaps(
+        cfg, seed, np.stack([prompts[i] for i in pick]), served[pick],
+        lowp=lowp)
     log(f"reference: {gaps.size} served tokens of {n} requests; "
         f"tokens off the reference's choice {int((gaps > 0).sum())}; "
         f"gap p50 {float(np.median(gaps))!r} max {float(gaps.max())!r}")

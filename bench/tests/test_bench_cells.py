@@ -1,12 +1,20 @@
 """Each cell's driver end to end on the CPU at a smoke size, its reference
-comparison included; a cell added from new files only; the exit without
-a chip; and the faults ``correct`` has to catch.
+comparison included; a cell, and a model family, added from new files
+only; the exit without a chip; and the faults ``correct`` has to catch.
 
 The run's own look for a chip is skipped: these call ``run.execute``
 with the CPU's devices, which the benchmark itself never does.  The
 market cell is out of ``BENCHMARK.json`` until its compared number is
 steady on the chip (``PERF.md``); its entries wait in
 ``market_cell.json`` and are added back here, in a copy of the benchmark.
+
+A cell's smoke sizes are files beside the tests, one per configuration
+and one per traffic mix: ``smoke/configs/<config>.json`` and
+``smoke/traffic/<traffic>.json``.  Each holds ``sizes``, the keys it
+overrides, and may hold ``control``: the control's sizes, with the
+control's name in the configuration's file and the number it is read on
+in the traffic's.  A cell whose two files both hold ``control`` has its
+control checked (``test_bench_control.py``).
 """
 import json
 import os
@@ -32,19 +40,22 @@ SEED = 2**31 + 4242  # more than a signed 32-bit word holds
 V5E = peaks.PEAKS["TPU v5 lite"]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 MARKET = json.loads((BENCH / "tests" / "market_cell.json").read_text())
-CELLS = [w["name"] for w in SPEC["workloads"] + MARKET["workloads"]]
+WORKLOADS = SPEC["workloads"] + MARKET["workloads"]
+CELLS = [w["name"] for w in WORKLOADS]
 
-SMOKE_DECODER = dict(hidden_size=128, intermediate_size=256,
-                     num_hidden_layers=2, num_attention_heads=4,
-                     num_key_value_heads=2, vocab_size=1024)
-SMOKE_TRAFFIC = {
-    "chat": dict(rate_per_s=12.0, prompt_tokens=16, max_new=5, max_batch=4,
-                 trace_window_s=[0.2, 0.8]),
-    "docs": dict(wave=4, max_waves=2000, prompt_tokens=24, max_new=3,
-                 max_batch=4, trace_window_s=[0.2, 0.8], check_requests=6),
-    "cycle": {},
-}
-SMOKE_CONFIG = {"qwen2-1.5b": SMOKE_DECODER, "market-10k": dict(parties=240)}
+
+def smoke_files(root: Path, config: str, traffic: str):
+    """The smoke files of a configuration and a traffic mix under the
+    benchmark at ``root``, read; fails naming each file that is missing."""
+    smoke = root / "bench" / "tests" / "smoke"
+    paths = [smoke / "configs" / f"{config}.json",
+             smoke / "traffic" / f"{traffic}.json"]
+    missing = [str(p.relative_to(root)) for p in paths if not p.exists()]
+    if missing:
+        raise FileNotFoundError(
+            f"no smoke sizes for config {config!r} and traffic {traffic!r}: "
+            f"add {' and '.join(missing)}")
+    return [json.loads(p.read_text()) for p in paths]
 
 
 def with_market(root: Path) -> Path:
@@ -64,9 +75,11 @@ def bench_root(tmp_path_factory):
 
 
 def smoke_cell(name: str, root: Path) -> harness.Cell:
+    """The cell ``name`` of the benchmark at ``root``, at its smoke sizes."""
     cell = harness.load_cell(name, root=root)
-    cell.config = dict(cell.config, **SMOKE_CONFIG[cell.config_name])
-    cell.traffic = dict(cell.traffic, **SMOKE_TRAFFIC[cell.traffic_name])
+    config, traffic = smoke_files(root, cell.config_name, cell.traffic_name)
+    cell.config = dict(cell.config, **config["sizes"])
+    cell.traffic = dict(cell.traffic, **traffic["sizes"])
     return cell
 
 
@@ -87,17 +100,32 @@ def test_cell_runs_end_to_end_at_smoke_size(name, bench_root):
     assert result["device"]["platform"] == "cpu"
 
 
-def test_a_new_cell_is_found_by_name_from_new_files_only(tmp_path):
-    shutil.copytree(BENCH, tmp_path / "bench",
+def _toy_family(root: Path, *, family=True, smoke=True) -> Path:
+    """A copy of the benchmark under ``root`` with one more cell, all of it
+    new files and entries: a config of a model family the drivers have
+    never seen, its mapping, reference, traffic, smoke files and a reader.
+    Copies of the Qwen2 files stand in for a second family's."""
+    shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "data"))
+    bench = root / "bench"
     spec = json.loads(json.dumps(SPEC))
-    (tmp_path / "bench/configs/toy-decoder.json").write_text(json.dumps(
-        dict(json.loads((BENCH / "configs/qwen2-1.5b.json").read_text()),
-             name="toy-decoder", **SMOKE_DECODER)))
-    (tmp_path / "bench/traffic/tiny-chat.json").write_text(json.dumps(
-        dict(json.loads((BENCH / "traffic/chat.json").read_text()),
-             **SMOKE_TRAFFIC["chat"])))
-    (tmp_path / "bench/metrics/requests_seen.toy.py").write_text(
+    (bench / "configs/toy-decoder.json").write_text(json.dumps(dict(
+        json.loads((BENCH / "configs/qwen2-1.5b.json").read_text()),
+        name="toy-decoder", model_type="toyfam",
+        reference="refs/toy_decoder.py")))
+    shutil.copy(BENCH / "refs/dense_decoder.py", bench / "refs/toy_decoder.py")
+    if family:
+        shutil.copy(BENCH / "families/qwen2.py", bench / "families/toyfam.py")
+    shutil.copy(BENCH / "traffic/chat.json", bench / "traffic/tiny-chat.json")
+    if smoke:
+        sizes = json.loads(
+            (BENCH / "tests/smoke/configs/qwen2-1.5b.json").read_text())
+        sizes["sizes"]["hidden_size"] = 64  # not the Qwen2 file's
+        (bench / "tests/smoke/configs/toy-decoder.json").write_text(
+            json.dumps(sizes))
+        shutil.copy(BENCH / "tests/smoke/traffic/chat.json",
+                    bench / "tests/smoke/traffic/tiny-chat.json")
+    (bench / "metrics/requests_seen.toy.py").write_text(
         "def read(run):\n"
         "    return float(len(run.facts['queue_wait_ms']))\n")
     spec["configs"].append({"name": "toy-decoder", "source": "test",
@@ -112,13 +140,42 @@ def test_a_new_cell_is_found_by_name_from_new_files_only(tmp_path):
                               "better": "higher", "source": "host_clock",
                               "layer": "slot queue", "moves": "ttft_p95_ms",
                               "workloads": ["toy-decoder.tiny-chat"]})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root
 
-    cell = harness.load_cell("toy-decoder.tiny-chat", root=tmp_path)
-    assert cell.config["hidden_size"] == SMOKE_DECODER["hidden_size"]
-    result, _ = execute(cell, trace=True)
-    assert result["correct"]
+
+def test_a_new_cell_is_found_by_name_from_new_files_only(tmp_path):
+    root = _toy_family(tmp_path)
+    cell = smoke_cell("toy-decoder.tiny-chat", root)
+    assert cell.config["model_type"] == "toyfam"
+    assert cell.config["hidden_size"] == 64
+    assert cell.traffic["prompt_tokens"] == json.loads(
+        (root / "bench/tests/smoke/traffic/tiny-chat.json").read_text()
+    )["sizes"]["prompt_tokens"]
+    result, checks = execute(cell, trace=True)
+    assert result["correct"], checks
     assert result["metrics"]["requests_seen.toy"]["value"] > 0
+    # the copy's own mapping and reference ran, found by the config's keys
+    loaded = {Path(m.__file__).relative_to(root).as_posix()
+              for m in list(sys.modules.values())
+              if getattr(m, "__file__", None)
+              and Path(m.__file__).is_relative_to(root)}
+    assert {"bench/families/toyfam.py", "bench/refs/toy_decoder.py"} <= loaded
+
+
+def test_an_unknown_model_family_fails_at_setup_naming_its_file(tmp_path):
+    cell = smoke_cell("toy-decoder.tiny-chat",
+                      _toy_family(tmp_path, family=False))
+    with pytest.raises(SystemExit, match="bench/families/toyfam.py"):
+        execute(cell)
+
+
+def test_a_cell_without_smoke_files_fails_naming_them(tmp_path):
+    root = _toy_family(tmp_path, smoke=False)
+    with pytest.raises(FileNotFoundError) as err:
+        smoke_cell("toy-decoder.tiny-chat", root)
+    assert "bench/tests/smoke/configs/toy-decoder.json" in str(err.value)
+    assert "bench/tests/smoke/traffic/tiny-chat.json" in str(err.value)
 
 
 @pytest.mark.parametrize(

@@ -2,9 +2,10 @@
 described TPU v5e chip (no chip attached), and check that each fits in
 the chip's 16 GB.
 
-The serving cells warm the prefill and decode steps of
-``launch/serve.build_engine``; the chat cell's largest slot is 16 rows
-of 1,024 tokens and the docs cell's 16 of 2,048.  The market cell's
+Every cell of ``BENCHMARK.json`` that the serving driver runs warms the
+prefill and decode steps of ``launch/serve.build_engine``, at most
+``max_batch`` rows of ``prompt_tokens`` (the chat cell's largest slot is
+16 rows of 1,024 tokens, the docs cell's 16 of 2,048).  The market cell's
 largest program is the distill cycle of its largest bucket: all 8,000 LR
 parties as students of MLP teachers.
 """
@@ -25,6 +26,10 @@ import harness  # noqa: E402
 import peaks  # noqa: E402
 
 HBM = peaks.PEAKS["TPU v5 lite"]["hbm_bytes"]
+SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+SERVED = [w["name"] for w in SPEC["workloads"]
+          if json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
+                        .read_text())["driver"] == "serve"]
 
 
 @pytest.fixture(scope="module")
@@ -60,10 +65,8 @@ def _bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("cell,kind", [
-    ("qwen2-1.5b.chat", "prefill"), ("qwen2-1.5b.chat", "decode"),
-    ("qwen2-1.5b.docs", "prefill"), ("qwen2-1.5b.docs", "decode"),
-])
+@pytest.mark.parametrize("cell,kind", [(c, k) for c in SERVED
+                                       for k in ("prefill", "decode")])
 def test_serving_program_fits_one_v5e(one_chip, cell, kind):
     from repro.launch.serve import build_engine
 
@@ -74,7 +77,7 @@ def test_serving_program_fits_one_v5e(one_chip, cell, kind):
     model, prefill, decode = build_engine(mcfg, tr["prompt_tokens"],
                                           tr["max_new"])
     params = _on(one_chip, jax.eval_shape(
-        lambda: drv.program_params(model, 0, jnp.bfloat16)))
+        lambda: drv.program_params(model, 0, jnp.dtype(mcfg.dtype))))
     b = tr["max_batch"]
     if kind == "prefill":
         tokens = jax.ShapeDtypeStruct((b, tr["prompt_tokens"]), jnp.int32)

@@ -5,7 +5,9 @@ precision lower: float8 for the bfloat16 decoder, bfloat16 matmuls for
 the float32 market.  Here, at a size a test run holds on the CPU, the
 control's reading has to lie well above the program's and fail the
 cell's own limit, which the program passes, as on the chip at the
-cells' own sizes (``control.py``; readings in ``PERF.md``).
+cells' own sizes (``control.py``; readings in ``PERF.md``).  The
+control's sizes, name and number come from the cell's smoke files
+(``test_bench_cells.py``).
 """
 import sys
 import time
@@ -20,23 +22,34 @@ sys.path[:0] = [str(HERE), str(HERE.parent), str(HERE.parents[1] / "src")]
 import control  # noqa: E402
 import harness  # noqa: E402
 import run  # noqa: E402
-from test_bench_cells import smoke_cell, with_market  # noqa: E402
+from test_bench_cells import (  # noqa: E402
+    BENCH, WORKLOADS, smoke_cell, smoke_files, with_market)
 
 SEEDS = (2**31 + 17, 2**31 + 18)
-# wide enough that float8 moves the decoder's logits past the cell's own
-# limit, as it does at the published widths
-CONTROL_SIZE = {
-    "qwen2-1.5b": dict(hidden_size=512, intermediate_size=1024,
-                       num_attention_heads=4, num_key_value_heads=2,
-                       vocab_size=4096),
-    "chat": dict(prompt_tokens=32, max_new=17, check_requests=6),
-}
+
+
+def controlled():
+    """``(cell, control, number)`` for each cell whose two smoke files say
+    how to read its control (a cell without them fails in
+    ``test_bench_cells.py``, naming them)."""
+    out = []
+    for w in WORKLOADS:
+        try:
+            config, traffic = smoke_files(BENCH.parent, w["config"],
+                                          w["traffic"])
+        except FileNotFoundError:
+            continue
+        if "control" in config and "control" in traffic:
+            out.append((w["name"], config["control"]["name"],
+                        traffic["control"]["number"]))
+    return out
 
 
 def readings(name, root):
     cell = smoke_cell(name, root)
-    cell.config.update(CONTROL_SIZE.get(cell.config_name, {}))
-    cell.traffic.update(CONTROL_SIZE.get(cell.traffic_name, {}))
+    config, traffic = smoke_files(root, cell.config_name, cell.traffic_name)
+    cell.config.update(config["control"]["sizes"])
+    cell.traffic.update(traffic["control"]["sizes"])
     drv = harness.driver_for(cell)
     read = control.market if cell.traffic["driver"] == "exchange" \
         else control.serving
@@ -48,10 +61,7 @@ def readings(name, root):
     return out
 
 
-@pytest.mark.parametrize("name,control_key,number", [
-    ("qwen2-1.5b.chat", "control_fp8", "served_token_gap"),
-    ("market-10k.cycle", "control_bf16", "param_rel_err"),
-])
+@pytest.mark.parametrize("name,control_key,number", controlled())
 def test_control_reads_far_above_the_program(name, control_key, number,
                                              tmp_path):
     rs = readings(name, with_market(tmp_path))
