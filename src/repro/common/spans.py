@@ -104,7 +104,9 @@ class _On:
             rec.spans, rec.stale = [], False
         stack = rec.stack()
         parent = stack[-1] if stack else None
-        ids = self.ids or (parent.ids if parent is not None else {})
+        ids = self.ids
+        if parent is not None and parent.ids:
+            ids = {**parent.ids, **ids} if ids else parent.ids
         self.annotation = TraceAnnotation(f"repro:{self.name}")
         self.annotation.__enter__()
         s = Span(self.name, time.perf_counter_ns(), None, parent, ids)
@@ -123,9 +125,10 @@ class _On:
 def span(name: str, **ids):
     """A context manager that records the work inside it as ``name``.
 
-    ``ids`` identify what the work was for (a slot, its requests).  A
-    span given none carries its parent's, so every span under one slot
-    carries that slot's ids.  With the profiler off it records nothing.
+    ``ids`` identify what the work was for (a slot, its requests, a
+    step's rows).  A span carries its parent's ids with its own added, so
+    every span under one slot carries that slot's ids.  With the profiler
+    off it records nothing.
     """
     if not TraceAnnotation.is_enabled():
         _RECORD.stale = True

@@ -1,4 +1,10 @@
-"""Minitron 4B — pruned Nemotron-4 (GQA, squared-ReLU) [arXiv:2407.14679]."""
+"""Minitron 4B — pruned and distilled Nemotron-4 [arXiv:2407.14679].
+
+Nemotron's block (nvidia/Minitron-4B-Base ``config.json``): GQA 24:8,
+non-gated squared-ReLU MLP, LayerNorm1P (a biased LayerNorm whose scale is
+``1 + weight``), RoPE over the first half of each head, and an output head
+apart from the embedding.
+"""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -12,6 +18,11 @@ CONFIG = ModelConfig(
     d_ff=9216,
     vocab_size=256000,
     mlp_type="squared_relu",
+    rope_theta=10_000.0,
+    rope_fraction=0.5,
+    norm="layernorm1p",
+    norm_eps=1e-5,
+    tie_embeddings=False,
 )
 
 SMOKE = CONFIG.replace(

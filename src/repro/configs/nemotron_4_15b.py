@@ -1,4 +1,8 @@
-"""Nemotron-4 15B — dense, GQA, squared-ReLU MLP [arXiv:2402.16819]."""
+"""Nemotron-4 15B — dense, GQA, squared-ReLU MLP [arXiv:2402.16819].
+
+The block Minitron 4B was pruned from: LayerNorm1P, RoPE over the first
+half of each head, untied input and output embeddings, no biases.
+"""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -13,6 +17,10 @@ CONFIG = ModelConfig(
     vocab_size=256000,
     mlp_type="squared_relu",
     rope_theta=10_000.0,
+    rope_fraction=0.5,
+    norm="layernorm1p",
+    norm_eps=1e-5,
+    tie_embeddings=False,
 )
 
 SMOKE = CONFIG.replace(

@@ -10,7 +10,10 @@ max-tokens; rows land back at their original request index.
 Each slot runs inside ``repro.common.spans`` spans (``serve.slot``,
 ``serve.pad``, ``serve.prefill``, ``serve.decode_step`` and, inside the
 last two, ``serve.dispatch``, ``serve.wait`` and ``serve.fetch``), which
-record only while a profiler trace is being taken.
+record only while a profiler trace is being taken.  Each carries the
+slot's ids (``slot``, ``requests``); ``serve.prefill`` and
+``serve.decode_step`` add the slot's ``rows``, and a decode step the cache
+position ``pos`` it writes.
 
 Example (CPU smoke):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2_1_5b --smoke \
@@ -71,7 +74,7 @@ def run_slot(cfg, prefill_fn, serve_fn, params, prompts, bucket, max_new):
     # tokens start their copy to the host at dispatch, as they do when a
     # bare np.asarray is the only sync: split into a wait and a fetch, the
     # sync then blocks the host no longer
-    with spans.span("serve.prefill"):
+    with spans.span("serve.prefill", rows=len(prompts)):
         with spans.span("serve.dispatch"):
             logits, cache = prefill_fn(params, batch)
             next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
@@ -81,8 +84,10 @@ def run_slot(cfg, prefill_fn, serve_fn, params, prompts, bucket, max_new):
         with spans.span("serve.fetch"):
             outs = [np.asarray(next_tok)[:, 0]]
     t1 = time.perf_counter()
-    for _ in range(max_new - 1):
-        with spans.span("serve.decode_step"):
+    for j in range(max_new - 1):
+        # ``pos``: the cache position this step's token is written to
+        with spans.span("serve.decode_step", rows=len(prompts),
+                        pos=bucket + j):
             with spans.span("serve.dispatch"):
                 tok, logits, cache = serve_fn(params, cache, {"token": next_tok})
                 tok.copy_to_host_async()

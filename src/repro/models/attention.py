@@ -167,8 +167,8 @@ def attend_full(
 ):
     """Self-attention over a contiguous sequence (train / prefill)."""
     q, k, v = _project_qkv(params, cfg, x)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
     S = q.shape[1]
     if cfg.attn_chunk and S > cfg.attn_chunk and S % cfg.attn_chunk == 0:
         out = _attend_chunked(q, k, v, positions, causal=causal, window=window,
@@ -267,8 +267,8 @@ def decode_step(params, cfg: ModelConfig, cache, x, pos):
     """
     q, k, v = _project_qkv(params, cfg, x)
     positions = jnp.full((x.shape[0], 1), pos, jnp.int32)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
     B, _, H, hd = q.shape
     KV, T = cache["k"].shape[1], cache["k"].shape[2]
     qg = q.reshape(B, KV, H // KV, hd)

@@ -27,6 +27,11 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     sliding_window: Optional[int] = None  # None = full causal attention
+    rope_fraction: float = 1.0  # leading share of each head's dims RoPE rotates
+    # norms and output head of the decoder blocks
+    norm: str = "rmsnorm"  # rmsnorm | layernorm1p (biased LayerNorm, scale 1 + w)
+    norm_eps: float = 1e-6
+    tie_embeddings: bool = True  # False: a separate (d_model, vocab) head
     # mlp
     mlp_type: str = "swiglu"  # swiglu | squared_relu | gelu
     # MoE

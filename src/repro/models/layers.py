@@ -1,4 +1,5 @@
-"""Shared layer primitives: norms, embeddings, MLP variants, RoPE."""
+"""Shared layer primitives: norms, embeddings, output head, MLP variants,
+RoPE."""
 from __future__ import annotations
 
 import jax
@@ -28,13 +29,6 @@ def rmsnorm(params, x, eps: float = 1e-6):
     return (y * params["scale"].astype(jnp.float32)).astype(dtype)
 
 
-def layernorm_spec(dim: int):
-    return {
-        "scale": ParamSpec((dim,), (AXIS_EMBED,), init="ones"),
-        "bias": ParamSpec((dim,), (AXIS_EMBED,), init="zeros"),
-    }
-
-
 def layernorm(params, x, eps: float = 1e-5):
     dtype = x.dtype
     x = x.astype(jnp.float32)
@@ -43,6 +37,20 @@ def layernorm(params, x, eps: float = 1e-5):
     y = (x - mean) * jax.lax.rsqrt(var + eps)
     y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
     return y.astype(dtype)
+
+
+def layernorm1p_spec(dim: int):
+    # the stored weight is zero-centred: the scale applied is 1 + weight
+    return {
+        "weight": ParamSpec((dim,), (AXIS_EMBED,), init="zeros"),
+        "bias": ParamSpec((dim,), (AXIS_EMBED,), init="zeros"),
+    }
+
+
+def layernorm1p(params, x, eps: float = 1e-5):
+    """LayerNorm with a bias whose scale is ``1 + weight`` (Nemotron)."""
+    scale = 1.0 + params["weight"].astype(jnp.float32)
+    return layernorm({"scale": scale, "bias": params["bias"]}, x, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -61,6 +69,15 @@ def embed(params, tokens):
 def unembed(params, x):
     # tied output head: logits = x @ table.T
     return jnp.einsum("...d,vd->...v", x, params["table"])
+
+
+def head_spec(dim: int, vocab: int):
+    """An output head of its own, stored (dim, vocab): input dim first."""
+    return {"w": ParamSpec((dim, vocab), (AXIS_EMBED, AXIS_VOCAB))}
+
+
+def head(params, x):
+    return jnp.einsum("...d,dv->...v", x, params["w"])
 
 
 # ---------------------------------------------------------------------------
@@ -108,9 +125,18 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def apply_rope(x, positions, theta: float, fraction: float = 1.0):
+    """x: (..., seq, heads, head_dim); positions: (..., seq).
+
+    With ``fraction`` < 1 only the leading ``fraction * head_dim`` dims of
+    each head are rotated, as a head of that size; the rest pass through.
+    """
     head_dim = x.shape[-1]
+    rot = int(head_dim * fraction)
+    if rot < head_dim:
+        assert rot % 2 == 0, (head_dim, fraction)
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], positions, theta), x[..., rot:]], axis=-1)
     freqs = rope_freqs(head_dim, theta)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., :, None, :]  # (..., seq, 1, hd/2)

@@ -33,6 +33,10 @@ from repro.sharding.rules import constrain
 from repro.models.layers import (
     embed,
     embedding_spec,
+    head,
+    head_spec,
+    layernorm1p,
+    layernorm1p_spec,
     mlp_apply,
     mlp_spec,
     rmsnorm,
@@ -53,15 +57,42 @@ class Model(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
+# Norms and output head, as the config names them
+# ---------------------------------------------------------------------------
+
+
+def _norm_spec(cfg: ModelConfig):
+    if cfg.norm == "layernorm1p":
+        return layernorm1p_spec(cfg.d_model)
+    if cfg.norm == "rmsnorm":
+        return rmsnorm_spec(cfg.d_model)
+    raise ValueError(f"unknown norm {cfg.norm!r}")
+
+
+def _norm(cfg: ModelConfig, p, x):
+    if cfg.norm == "layernorm1p":
+        return layernorm1p(p, x, cfg.norm_eps)
+    return rmsnorm(p, x, cfg.norm_eps)
+
+
+def _logits(cfg: ModelConfig, params, x):
+    """The final norm, then the tied head or the model's own."""
+    x = _norm(cfg, params["final_norm"], x)
+    if cfg.tie_embeddings:
+        return unembed(params["embed"], x)
+    return head(params["head"], x)
+
+
+# ---------------------------------------------------------------------------
 # Block specs
 # ---------------------------------------------------------------------------
 
 
 def _attn_block_spec(cfg: ModelConfig):
     spec = {
-        "ln1": rmsnorm_spec(cfg.d_model),
+        "ln1": _norm_spec(cfg),
         "attn": attn.attention_spec(cfg),
-        "ln2": rmsnorm_spec(cfg.d_model),
+        "ln2": _norm_spec(cfg),
     }
     if cfg.is_moe:
         spec["moe"] = moe_lib.moe_spec(cfg)
@@ -107,9 +138,11 @@ def _n_super(cfg: ModelConfig) -> int:
 def decoder_param_specs(cfg: ModelConfig):
     specs = {
         "embed": embedding_spec(cfg.vocab_size, cfg.d_model),
-        "final_norm": rmsnorm_spec(cfg.d_model),
+        "final_norm": _norm_spec(cfg),
         "blocks": stack_specs(_super_block_spec(cfg), _n_super(cfg)),
     }
+    if not cfg.tie_embeddings:
+        specs["head"] = head_spec(cfg.d_model, cfg.vocab_size)
     if cfg.family == "hybrid":
         specs["shared_attn"] = _attn_block_spec(
             cfg.replace(num_experts=0)  # shared block is dense attn+mlp
@@ -124,14 +157,14 @@ def decoder_param_specs(cfg: ModelConfig):
 
 def _attn_block_apply(p, cfg: ModelConfig, x, positions, *, window):
     h, kv = attn.attend_full(
-        p["attn"], cfg, rmsnorm(p["ln1"], x), positions, window=window
+        p["attn"], cfg, _norm(cfg, p["ln1"], x), positions, window=window
     )
     x = x + h
     losses = {}
     if "moe" in p:
-        y, losses = moe_lib.moe_apply(p["moe"], cfg, rmsnorm(p["ln2"], x))
+        y, losses = moe_lib.moe_apply(p["moe"], cfg, _norm(cfg, p["ln2"], x))
     else:
-        y = mlp_apply(cfg.mlp_type, p["mlp"], rmsnorm(p["ln2"], x))
+        y = mlp_apply(cfg.mlp_type, p["mlp"], _norm(cfg, p["ln2"], x))
     x = x + y
     return x, kv, losses
 
@@ -222,8 +255,7 @@ def decoder_forward(
     )
     if last_logit_only:
         x = x[:, -1:]
-    x = rmsnorm(params["final_norm"], x)
-    logits = unembed(params["embed"], x)
+    logits = _logits(cfg, params, x)
     aux_out = {"moe_aux": aux, "moe_z": z}
     if collect_cache:
         return logits, aux_out, (kvs, positions)
@@ -335,13 +367,13 @@ def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
     """
     if cfg.family in ("dense", "moe", "vlm"):
         h, token_kv = attn.decode_step(
-            p["attn"], cfg, cache["kv"], rmsnorm(p["ln1"], x), pos
+            p["attn"], cfg, cache["kv"], _norm(cfg, p["ln1"], x), pos
         )
         x = x + h
         if "moe" in p:
-            y, _ = moe_lib.moe_apply(p["moe"], cfg, rmsnorm(p["ln2"], x))
+            y, _ = moe_lib.moe_apply(p["moe"], cfg, _norm(cfg, p["ln2"], x))
         else:
-            y = mlp_apply(cfg.mlp_type, p["mlp"], rmsnorm(p["ln2"], x))
+            y = mlp_apply(cfg.mlp_type, p["mlp"], _norm(cfg, p["ln2"], x))
         return x + y, {"kv": token_kv}
 
     if cfg.family == "hybrid":
@@ -353,10 +385,10 @@ def _super_decode(cfg: ModelConfig, shared, p, cache, x, pos):
 
         x, new_ssm = maybe_scan(mamba_body, x, (p["mamba"], cache["ssm"]))
         h, token_kv = attn.decode_step(
-            shared["attn"], cfg, cache["kv"], rmsnorm(shared["ln1"], x), pos
+            shared["attn"], cfg, cache["kv"], _norm(cfg, shared["ln1"], x), pos
         )
         x = x + h
-        y = mlp_apply(cfg.mlp_type, shared["mlp"], rmsnorm(shared["ln2"], x))
+        y = mlp_apply(cfg.mlp_type, shared["mlp"], _norm(cfg, shared["ln2"], x))
         return x + y, {"kv": token_kv, "ssm": new_ssm}
 
     if cfg.family == "ssm":
@@ -390,8 +422,7 @@ def decoder_decode(params, cfg: ModelConfig, cache, batch):
     blocks = {**cache["blocks"], **new}
     if "kv" in new:
         blocks["kv"] = attn.write_cache(cfg, cache["blocks"]["kv"], new["kv"], pos)
-    x = rmsnorm(params["final_norm"], x)
-    logits = unembed(params["embed"], x)
+    logits = _logits(cfg, params, x)
     return logits, {"blocks": blocks, "pos": pos + 1}
 
 

@@ -135,6 +135,14 @@ def test_assigned_configs_exact():
     assert get_config("zamba2_2_7b").ssm_state == 64
     assert get_config("qwen2_1_5b").qkv_bias
     assert get_config("nemotron_4_15b").mlp_type == "squared_relu"
+    # Nemotron's block: LayerNorm1P, rotary over half of each head, untied
+    for arch in ("minitron_4b", "nemotron_4_15b"):
+        cfg = get_config(arch)
+        assert (cfg.norm, cfg.norm_eps, cfg.rope_fraction, cfg.tie_embeddings,
+                cfg.rope_theta) == ("layernorm1p", 1e-5, 0.5, False, 10_000.0)
+    qwen2 = get_config("qwen2_1_5b")
+    assert (qwen2.norm, qwen2.norm_eps, qwen2.rope_fraction,
+            qwen2.tie_embeddings) == ("rmsnorm", 1e-6, 1.0, True)
 
 
 def test_input_shapes_table():

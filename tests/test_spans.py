@@ -103,6 +103,17 @@ def test_slot_spans_name_their_requests_and_hold_the_slot(traced):
         assert s.parent is None
 
 
+def test_steps_carry_their_rows_and_the_cache_position_they_write(traced):
+    record, _, _ = traced
+    for slot in _named(record, "serve.slot"):
+        rows = len(slot.ids["requests"])
+        pre, *steps = [c for c in slot.children if c.name != "serve.pad"]
+        assert pre.ids == {**slot.ids, "rows": rows}
+        assert [s.ids for s in steps] == [
+            {**slot.ids, "rows": rows, "pos": BUCKET + j}
+            for j in range(MAX_NEW - 1)]
+
+
 def test_t_prefill_runs_until_the_first_tokens_are_on_the_host(traced):
     record, slots, _ = traced
     for (_, _, t_prefill, t_decode), pre, slot in zip(
