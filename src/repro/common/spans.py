@@ -14,7 +14,9 @@ context that does nothing.
 
 :func:`lowerings` counts the programs JAX lowered, per jitted function.
 It is always on: it listens for JAX's lowering event, which fires once
-per new program and never in a warm step.
+per new program and never in a warm step.  :func:`attention_paths`,
+also always on, counts the self-attention layers traced, by the path each
+lowered and the share of its S × S scores that path computes.
 
   with spans.span("serve.slot", slot=0, requests=(3, 4)):
       ...
@@ -178,3 +180,21 @@ def lowerings() -> Dict[str, int]:
     (``prefill_step``, ``serve_step``, ``_argmax``, ...).  Each lowering
     ends in a compile or a read of the persistent compile cache."""
     return dict(_LOWERED)
+
+
+_ATTENTION: collections.Counter = collections.Counter()
+
+
+def note_attention(path: str, share: float) -> None:
+    """Count one traced self-attention layer that lowered ``path`` and
+    computes ``share`` of its S × S scores.  Called at trace time only,
+    so a warm step counts nothing."""
+    _ATTENTION[(path, share)] += 1
+
+
+def attention_paths() -> Dict[tuple, int]:
+    """Self-attention layers traced so far in this process, per
+    ``(path, share of the S × S scores computed)``: ``("dense", 1.0)``,
+    ``("causal_blocks", 0.625)`` for four query blocks, ``("chunked",
+    1.0)``."""
+    return dict(_ATTENTION)

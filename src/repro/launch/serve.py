@@ -21,7 +21,8 @@ Example (CPU smoke):
 
 With ``--trace-dir DIR`` the serving runs under ``jax.profiler.trace(DIR)``
 and prints, per span name, the count and the total and self milliseconds,
-then the programs lowered per jitted function.
+then the programs lowered per jitted function and the self-attention
+paths traced, each with the share of the S × S scores it computes.
 """
 from __future__ import annotations
 
@@ -177,6 +178,8 @@ def main(argv=None):
         for name, (n, total, own) in spans.totals(spans.recorded()).items():
             print(f"  {name:<18} {n:>6} {total / 1e6:>12.3f} {own / 1e6:>12.3f}")
         print(f"programs lowered: {spans.lowerings()}")
+        print(f"attention paths traced (path, share of S x S): "
+              f"{spans.attention_paths()}")
     return 0
 
 

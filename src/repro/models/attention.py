@@ -10,9 +10,11 @@ Layouts:
                                 decode attention reads, with no relayout
   cache.pos    (B, T) int32     absolute position per slot, -1 = empty
 
-Decode reads each layer's cache where it lies and returns only the new
-token's K/V; ``write_cache`` puts those into the stacked caches after the
-layer scan, in place when the cache is donated.
+Causal self-attention over a long sequence runs as query blocks, each
+scored only against its key prefix (``causal_blocks``).  Decode reads each
+layer's cache where it lies and returns only the new token's K/V;
+``write_cache`` puts those into the stacked caches after the layer scan,
+in place when the cache is donated.
 """
 from __future__ import annotations
 
@@ -21,12 +23,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.common import spans
 from repro.common.types import AXIS_EMBED, AXIS_HEADS, AXIS_KV, ParamSpec
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope
 from repro.sharding.rules import constrain
 
 NEG_INF = -1e30
+# rows per query block of causal self-attention (``causal_blocks``)
+CAUSAL_BLOCK = 512
 
 
 def _constrain_gqa(qg, k, v):
@@ -156,6 +161,50 @@ def _attend_chunked(q, k, v, positions, *, causal, window, chunk, out_dtype,
     return jnp.moveaxis(out, 3, 1).reshape(B, S, KV * G * hd)
 
 
+def causal_blocks(S: int) -> int:
+    """The query blocks causal self-attention over ``S`` rows splits into:
+    ``S // CAUSAL_BLOCK`` where that is two or more and exact, else 1."""
+    n, rest = divmod(S, CAUSAL_BLOCK)
+    return n if n >= 2 and rest == 0 else 1
+
+
+def _attend_dense(q, k, v, q_pos, k_pos, *, causal, window, out_dtype, pin):
+    """Masked softmax attention over all of ``k``: scores in q's dtype, an
+    f32 softmax, weights in ``out_dtype`` into the P·V einsum.  q roped
+    (B,S,H,hd), positions (B,S); k, v roped (B,T,KV,hd), positions (B,T)."""
+    scores = _gqa_scores(q, k, v, pin=pin)  # (B,KV,G,S,T)
+    i = q_pos[:, None, None, :, None]
+    j = k_pos[:, None, None, None, :]
+    mask = jnp.ones(scores.shape[-2:], dtype=bool)[None, None, None]
+    if causal:
+        mask = mask & (j <= i)
+    if window is not None:
+        mask = mask & (i - j < window)
+    scores = jnp.where(mask, scores, NEG_INF)
+    weights = _softmax(scores).astype(out_dtype)
+    return _gqa_out(weights, v, out_dtype)
+
+
+def _attend_causal_blocks(q, k, v, positions, n, *, window, out_dtype, pin):
+    """Causal attention as ``n`` query blocks, each over its key prefix.
+
+    Block ``i`` scores its rows against keys ``[0, (i+1)·S/n)`` only, with
+    one exact softmax there: the keys after it, all masked, are never
+    computed, so the work is ``(n+1)/(2n)`` of the dense path's.  With
+    ``positions`` ascending from 0 along each row, as every caller's are,
+    the result is the dense one.
+    """
+    b = q.shape[1] // n
+    outs = []
+    for i in range(n):
+        lo, hi = i * b, (i + 1) * b
+        outs.append(_attend_dense(
+            q[:, lo:hi], k[:, :hi], v[:, :hi], positions[:, lo:hi],
+            positions[:, :hi], causal=True, window=window,
+            out_dtype=out_dtype, pin=pin))
+    return jnp.concatenate(outs, axis=1)
+
+
 def attend_full(
     params,
     cfg: ModelConfig,
@@ -165,27 +214,33 @@ def attend_full(
     causal: bool = True,
     window: Optional[int] = None,
 ):
-    """Self-attention over a contiguous sequence (train / prefill)."""
+    """Self-attention over a contiguous sequence (train / prefill).
+
+    Causal attention over two or more ``CAUSAL_BLOCK``-row blocks computes
+    only the blocks on and below the diagonal (``causal_blocks``).  Each
+    trace notes its path and the share of the S × S scores it computes in
+    ``spans.attention_paths()``.
+    """
     q, k, v = _project_qkv(params, cfg, x)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
     S = q.shape[1]
     if cfg.attn_chunk and S > cfg.attn_chunk and S % cfg.attn_chunk == 0:
+        spans.note_attention("chunked", 1.0)
         out = _attend_chunked(q, k, v, positions, causal=causal, window=window,
                               chunk=cfg.attn_chunk, out_dtype=x.dtype,
                               pin=cfg.attn_pin_kv)
         return jnp.einsum("bsh,hd->bsd", out, params["wo"]), (k, v)
-    scores = _gqa_scores(q, k, v, pin=cfg.attn_pin_kv)  # (B,KV,G,S,T), T == S
-    i = positions[:, None, None, :, None]
-    j = positions[:, None, None, None, :]
-    mask = jnp.ones(scores.shape[-2:], dtype=bool)[None, None, None]
-    if causal:
-        mask = mask & (j <= i)
-    if window is not None:
-        mask = mask & (i - j < window)
-    scores = jnp.where(mask, scores, NEG_INF)
-    weights = _softmax(scores).astype(x.dtype)
-    out = _gqa_out(weights, v, x.dtype)
+    n = causal_blocks(S) if causal else 1
+    if n > 1:
+        spans.note_attention("causal_blocks", (n + 1) / (2 * n))
+        out = _attend_causal_blocks(q, k, v, positions, n, window=window,
+                                    out_dtype=x.dtype, pin=cfg.attn_pin_kv)
+    else:
+        spans.note_attention("dense", 1.0)
+        out = _attend_dense(q, k, v, positions, positions, causal=causal,
+                            window=window, out_dtype=x.dtype,
+                            pin=cfg.attn_pin_kv)
     return jnp.einsum("bsh,hd->bsd", out, params["wo"]), (k, v)
 
 

@@ -4,8 +4,9 @@ What the TPU compiler refuses (a block layout Mosaic rejects, a program
 larger than the chip's memory) fails here instead of on the chip.  The
 ``kd_loss`` kernel compiles at the distill cycle's shape under ``vmap``
 and at a 151936-word vocabulary; the full-width Qwen2-1.5B prefill and
-decode steps compile within one chip's 16 GB, and the decode step
-updates its donated KV cache in place.
+decode steps compile within one chip's 16 GB, the docs prefill holds no
+dense attention score tensor, and the decode step updates its donated KV
+cache in place.
 """
 import math
 import re
@@ -84,6 +85,23 @@ def test_qwen2_1_5b_serving_step_fits_one_v5e(one_chip, kind):
         args = (params, cache, _on(one_chip, {"token": token}))
     compiled = jax.jit(step).lower(*args).compile()
     assert cost_summary(compiled)["peak_bytes"] < V5E_HBM_BYTES
+
+
+def test_qwen2_1_5b_docs_prefill_holds_no_dense_score_tensor(one_chip):
+    """The docs cell's prefill, 16 prompts of 2,048, computes causal
+    attention in query blocks over their key prefix: its temporaries stay
+    below one layer's dense bf16 score tensor, which the dense path
+    held with its f32 softmax besides."""
+    cfg = get_config("qwen2_1_5b")
+    batch, bucket, max_new = 16, 2048, 16
+    prefill, model = make_prefill_step(cfg, cache_len=bucket + max_new)
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((batch, bucket), jnp.int32)
+    compiled = jax.jit(prefill).lower(
+        params, _on(one_chip, {"tokens": tokens})).compile()
+    dense_scores = batch * cfg.num_heads * bucket ** 2 * 2
+    assert dense_scores == 1_610_612_736
+    assert compiled.memory_analysis().temp_size_in_bytes < dense_scores
 
 
 def _cache_sized_copies(hlo: str, layers: int, batch: int, length: int):

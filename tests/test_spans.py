@@ -13,6 +13,7 @@ from repro.common import spans
 from repro.common.spans import Span, self_ns
 from repro.configs import get_smoke_config
 from repro.launch import serve
+from repro.models import attention
 
 BUCKET, MAX_NEW, MAX_BATCH = 16, 4, 2
 
@@ -179,6 +180,37 @@ def test_a_new_slot_size_lowers_each_step_once_and_a_repeat_none():
         assert second[fn] - first[fn] == 1
 
 
+def _lowered_by_serving(monkeypatch, block):
+    """Programs lowered and attention paths traced while a fresh engine
+    with ``block``-row query blocks serves slots of 1, 1 and 2 rows."""
+    monkeypatch.setattr(attention, "CAUSAL_BLOCK", block)
+    engine = _engine()
+    lowered, paths = spans.lowerings(), spans.attention_paths()
+    for n in (1, 1, 2):
+        _serve(engine, n)
+
+    def grown(before, after):
+        return {k: v - before.get(k, 0) for k, v in after.items()
+                if v != before.get(k, 0)}
+
+    return (grown(lowered, spans.lowerings()),
+            grown(paths, spans.attention_paths()))
+
+
+def test_blocked_prefill_attention_lowers_the_programs_dense_does(
+        monkeypatch):
+    """Query blocks of prefill attention live inside the prefill program:
+    each slot size lowers the same programs per jitted function as on the
+    dense path, and no more."""
+    _lowered_by_serving(monkeypatch, BUCKET + 1)  # eager ops, once
+    blocked, blocked_paths = _lowered_by_serving(monkeypatch, BUCKET // 2)
+    dense, dense_paths = _lowered_by_serving(monkeypatch, BUCKET + 1)
+    assert blocked == dense
+    assert blocked["prefill_step"] == blocked["serve_step"] == 2
+    assert set(blocked_paths) == {("causal_blocks", 0.75)}
+    assert set(dense_paths) == {("dense", 1.0)}
+
+
 @pytest.mark.parametrize("which, program", [(1, "jit_prefill_step"),
                                             (2, "jit_serve_step")])
 def test_engine_programs_keep_the_names_the_benchmark_matches(
@@ -196,6 +228,7 @@ def test_engine_programs_keep_the_names_the_benchmark_matches(
 
 def test_the_trace_dir_option_prints_the_spans(tmp_path, capsys, monkeypatch):
     monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(attention, "CAUSAL_BLOCK", 8)  # 4 blocks of 32
     assert serve.main(["--smoke", "--requests", "3", "--max-new", "3",
                        "--max-batch", "2",
                        "--trace-dir", str(tmp_path)]) == 0
@@ -206,4 +239,6 @@ def test_the_trace_dir_option_prints_the_spans(tmp_path, capsys, monkeypatch):
     assert rows["serve.decode_step"][0] == str(2 * 2)
     assert float(rows["serve.prefill"][1]) >= float(rows["serve.prefill"][2])
     assert "programs lowered: {" in out and "'serve_step'" in out
+    assert ("attention paths traced (path, share of S x S): {" in out
+            and "('causal_blocks', 0.625)" in out)
     assert list(tmp_path.rglob("*.xplane.pb"))
