@@ -16,7 +16,6 @@ output is both human-skimmable and machine-parsable.
   serving_overload— 4x regional spike: spillover + SLA refusals + restore
   durability_scale— full-world snapshot/restore + membership churn
   population_scale— scan-fused one-dispatch cycles vs per-step baseline
-  roofline        — three-term roofline from dry-run artifacts (if present)
 
 Usage: python -m benchmarks.run [sections...] [--json RESULTS.json]
 
@@ -179,15 +178,6 @@ def run_kernels():
     kmain(_json_args())
 
 
-def run_roofline():
-    from benchmarks.roofline import ART_DIR, main as rmain
-
-    if not any(ART_DIR.glob("*.json")):
-        print("roofline/skipped,0,no dry-run artifacts (run repro.launch.dryrun)")
-        return
-    rmain()
-
-
 def main():
     global _JSON_PATH
     argv = sys.argv[1:]
@@ -205,8 +195,7 @@ def main():
                           "continuum_scale", "exchange_scale",
                           "chaos_scale", "drift_scale", "hierarchy_scale",
                           "serving_scale", "serving_overload",
-                          "durability_scale", "population_scale",
-                          "roofline"}
+                          "durability_scale", "population_scale"}
     print("name,us_per_call,derived")
     if "fig3" in which:
         section("Fig.3 heterogeneity impact")
@@ -247,9 +236,6 @@ def main():
     if "traffic" in which:
         section("MDD vs FL traffic")
         run_traffic()
-    if "roofline" in which:
-        section("Roofline (from dry-run)")
-        run_roofline()
 
 
 if __name__ == "__main__":

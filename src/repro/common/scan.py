@@ -1,11 +1,8 @@
-"""Scan indirection for HLO cost accounting.
+"""Scan indirection that a caller can unroll.
 
-XLA's ``HloCostAnalysis`` visits a ``while`` body ONCE, ignoring trip
-counts, so a scanned-over-layers model reports ~1 layer of FLOPs.  The
-production lowering keeps ``lax.scan`` (small HLO, fast compile); the
-roofline pass re-lowers shallow unrolled variants under ``unroll_scans()``
-and extrapolates ``total = f(1) + (n-1) * (f(2) - f(1))`` (see
-benchmarks/roofline.py).
+Programs lower ``lax.scan`` (small HLO, fast compile).  Under
+``unroll_scans()`` the same code lowers as a Python loop instead, so one
+function can be checked in both forms (``tests/test_models.py``).
 
 ``maybe_scan`` is a drop-in for ``jax.lax.scan(body, init, xs)`` at every
 depth-axis (and sLSTM time-axis) scan site.

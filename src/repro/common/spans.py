@@ -194,7 +194,6 @@ def note_attention(path: str, share: float) -> None:
 
 def attention_paths() -> Dict[tuple, int]:
     """Self-attention layers traced so far in this process, per
-    ``(path, share of the S × S scores computed)``: ``("dense", 1.0)``,
-    ``("causal_blocks", 0.625)`` for four query blocks, ``("chunked",
-    1.0)``."""
+    ``(path, share of the S × S scores computed)``: ``("dense", 1.0)``
+    or ``("causal_blocks", 0.625)`` for four query blocks."""
     return dict(_ATTENTION)

@@ -4,16 +4,14 @@ Models declare their parameters as nested dicts of :class:`ParamSpec`
 (shape + logical sharding axes + initializer).  The same spec tree drives
 
   * parameter materialization (``init_params``),
-  * logical-axis extraction for sharding (``logical_axes``),
-  * abstract ``ShapeDtypeStruct`` stand-ins for the multi-pod dry-run
-    (``abstract_params``), and
+  * logical-axis extraction for sharding (``logical_axes``), and
   * stacked-layer variants for scan-over-layers (``stack_specs``).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -84,16 +82,6 @@ def init_params(spec_tree, key: jax.Array, dtype=None):
             arr = arr.astype(dtype)
         out.append(arr)
     return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def abstract_params(spec_tree, dtype=None):
-    """ShapeDtypeStruct stand-ins — used by the dry-run (no allocation)."""
-
-    def to_abstract(spec: ParamSpec):
-        dt = dtype if dtype is not None else spec.dtype
-        return jax.ShapeDtypeStruct(spec.shape, dt)
-
-    return jax.tree_util.tree_map(to_abstract, spec_tree, is_leaf=_is_spec)
 
 
 def logical_axes(spec_tree):

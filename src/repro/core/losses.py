@@ -40,78 +40,6 @@ def kd_kl_loss(student_logits, teacher_logits, temperature: float = 2.0, *, mask
     return kl * (t * t)
 
 
-def distillation_loss_chunked(
-    student_logits,
-    teacher_logits,
-    labels,
-    *,
-    alpha: float = 0.5,
-    temperature: float = 2.0,
-    chunk: int = 16384,
-):
-    """Online (vocab-chunked) distillation loss — the jnp mirror of the
-    fused Pallas kernel (kernels/kd_loss.py), same decomposition:
-
-      KL = (s_tt - s_ts)/l_t - logZ_t + logZ_s      (at temperature T)
-      CE = logZ_s1 - sl[label]                      (at T = 1)
-
-    Never materializes an (N, V) f32 softmax: the vocab axis streams in
-    chunks with running-max rescaling, cutting the KD loss's peak memory
-    from O(N·V) f32 to O(N·chunk).
-    """
-    from repro.common.scan import maybe_scan
-
-    t = temperature
-    sl2 = student_logits.reshape(-1, student_logits.shape[-1])
-    tl2 = teacher_logits.reshape(-1, teacher_logits.shape[-1])
-    lab = labels.reshape(-1)
-    N, V = sl2.shape
-    chunk = min(chunk, V)
-    nc = -(-V // chunk)
-    pad = nc * chunk - V
-    if pad:
-        sl2 = jnp.pad(sl2, ((0, 0), (0, pad)), constant_values=-1e30)
-        tl2 = jnp.pad(tl2, ((0, 0), (0, pad)), constant_values=-1e30)
-    sc = jnp.moveaxis(sl2.reshape(N, nc, chunk), 1, 0)
-    tc = jnp.moveaxis(tl2.reshape(N, nc, chunk), 1, 0)
-    offs = jnp.arange(nc) * chunk
-
-    def body(carry, inp):
-        m_s1, l_s1, gold, m_s, l_s, m_t, l_t, s_tt, s_ts = carry
-        sl, tl, off = inp
-        slf = sl.astype(jnp.float32)
-        tlf = tl.astype(jnp.float32)
-        cols = off + jnp.arange(chunk)
-        # student at T=1 (CE)
-        m1 = jnp.maximum(m_s1, jnp.max(slf, -1))
-        l_s1 = l_s1 * jnp.exp(m_s1 - m1) + jnp.sum(jnp.exp(slf - m1[:, None]), -1)
-        gold = gold + jnp.sum(
-            jnp.where(cols[None, :] == lab[:, None], slf, 0.0), -1)
-        # student at T
-        sl_t = slf / t
-        ms = jnp.maximum(m_s, jnp.max(sl_t, -1))
-        l_s = l_s * jnp.exp(m_s - ms) + jnp.sum(jnp.exp(sl_t - ms[:, None]), -1)
-        # teacher at T + weighted sums of tl_t and sl_t
-        tl_t = tlf / t
-        mt = jnp.maximum(m_t, jnp.max(tl_t, -1))
-        corr = jnp.exp(m_t - mt)
-        p = jnp.exp(tl_t - mt[:, None])
-        l_t = l_t * corr + jnp.sum(p, -1)
-        s_tt = s_tt * corr + jnp.sum(p * tl_t, -1)
-        s_ts = s_ts * corr + jnp.sum(p * sl_t, -1)
-        return (m1, l_s1, gold, ms, l_s, mt, l_t, s_tt, s_ts), None
-
-    neg = jnp.full((N,), -1e30, jnp.float32)
-    zero = jnp.zeros((N,), jnp.float32)
-    init = (neg, zero, zero, neg, zero, neg, zero, zero, zero)
-    (m_s1, l_s1, gold, m_s, l_s, m_t, l_t, s_tt, s_ts), _ = maybe_scan(
-        body, init, (sc, tc, offs))
-    ce = (m_s1 + jnp.log(l_s1)) - gold
-    kl = (s_tt - s_ts) / l_t - (m_t + jnp.log(l_t)) + (m_s + jnp.log(l_s))
-    ce_m, kl_m = jnp.mean(ce), jnp.mean(kl) * (t * t)
-    return alpha * ce_m + (1.0 - alpha) * kl_m, {"ce": ce_m, "kd": kl_m}
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def fused_distillation_loss(student_logits, teacher_logits, labels,
                             alpha=0.5, temperature=2.0):

@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
   flash_attention — GQA/causal/sliding-window attention (VMEM-tiled,
-                    online softmax); jnp mirror: models/attention.py
-                    (_attend_chunked) for the CPU/dry-run path.
+                    online softmax); not on the model path, whose prefill
+                    attention is models/attention.py.
   kd_loss         — fused distillation loss over large vocabs (the MDD
                     integration objective; no full softmax in HBM).
   ssd_scan        — Mamba2/SSD chunked scan (MXU matmul form, carried

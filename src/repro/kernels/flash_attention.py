@@ -1,6 +1,6 @@
 """Flash attention Pallas TPU kernel — GQA, causal, optional sliding window.
 
-TPU adaptation (DESIGN.md §4): Q/KV tiles are (block_q × head_dim) /
+TPU adaptation: Q/KV tiles are (block_q × head_dim) /
 (block_kv × head_dim) VMEM blocks with MXU-aligned dims; the KV axis is the
 innermost sequential grid dimension with online-softmax accumulators
 (m, l, acc) held in VMEM scratch across KV steps.  GQA is expressed in the

@@ -1,6 +1,6 @@
 """Mamba2 / SSD chunked-scan Pallas TPU kernel.
 
-TPU adaptation (DESIGN.md §4): the GPU reference uses warp-level parallel
+TPU adaptation: the GPU reference uses warp-level parallel
 scans; on TPU the intra-chunk work becomes dense (Q×Q)·(Q×P) MXU matmuls
 and the inter-chunk recurrence is carried in a VMEM scratch state (P,N)
 across the innermost sequential grid axis — no cross-chunk parallel scan

@@ -1,1 +1,1 @@
-"""Launcher: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launcher: meshes, step functions, compile cache, train/serve drivers."""

@@ -1,9 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as functions (never module-level constants) so importing this
-module never touches jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init; smoke tests and benches see the real single CPU device.
+module never touches jax device state.
 """
 from __future__ import annotations
 
@@ -24,12 +22,6 @@ def _auto_mesh(shape, axes):
     # (GSPMD), so every axis is ``Auto``; ``Explicit`` axes would make each
     # op's output sharding part of its type
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():

@@ -1,5 +1,5 @@
 """pjit-able step functions (train / prefill / decode / distill) and the
-abstract, sharding-annotated input specs the multi-pod dry-run lowers with.
+abstract, sharding-annotated input specs they lower with on a mesh.
 
 Every function here is pure and mesh-agnostic; shardings are attached to
 the ``ShapeDtypeStruct`` stand-ins (AOT pattern), so ``jax.jit(step)
@@ -8,18 +8,13 @@ device memory.
 """
 from __future__ import annotations
 
-import functools
-import os as _os
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common.scan import maybe_scan
 from repro.common.types import ParamSpec
-from repro.core.losses import (cross_entropy_loss, distillation_loss,
-                               distillation_loss_chunked)
+from repro.core.losses import cross_entropy_loss, distillation_loss
 from repro.models import build_model
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.optim import adamw, apply_updates, clip_by_global_norm
@@ -30,11 +25,7 @@ from repro.sharding import (
     param_pspecs_even,
 )
 
-LONG_CONTEXT_WINDOW = 8192  # sliding window used ONLY for long_500k (DESIGN §6)
-
-# §Perf-optimized defaults (EXPERIMENTS.md): baseline keeps the paper-faithful
-# settings; REPRO_OPTIMIZED=1 applies the hillclimb winners per shape kind.
-OPTIMIZED = _os.environ.get("REPRO_OPTIMIZED", "0") == "1"
+LONG_CONTEXT_WINDOW = 8192  # sliding window used ONLY for long_500k
 
 
 def resolve_config(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
@@ -48,12 +39,6 @@ def resolve_config(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
         return cfg.replace(sliding_window=LONG_CONTEXT_WINDOW)
     if shape.name == "long_500k" and cfg.family == "ssm" and cfg.block_type != "xlstm":
         cfg = cfg.replace(sliding_window=LONG_CONTEXT_WINDOW)
-    if OPTIMIZED:
-        if shape.kind == "train":
-            cfg = cfg.replace(seq_parallel=True, grad_accum_dtype="bfloat16",
-                              opt_moment_dtype="bfloat16")
-        if shape.kind == "prefill":
-            cfg = cfg.replace(attn_chunk=2048, attn_pin_kv=True)
     return cfg
 
 
@@ -62,17 +47,17 @@ def resolve_config(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
 # ---------------------------------------------------------------------------
 
 
-def make_optimizer(cfg: ModelConfig):
-    return adamw(3e-4, weight_decay=0.1,
-                 moment_dtype=jnp.dtype(cfg.opt_moment_dtype))
+def make_optimizer():
+    """AdamW with float32 moments."""
+    return adamw(3e-4, weight_decay=0.1)
 
 
-def make_train_step(cfg: ModelConfig, shape: ShapeConfig, *, accum_dtype=None):
-    """Gradient-accumulated train step: (params, opt_state, batch) -> ..."""
-    if accum_dtype is None:
-        accum_dtype = jnp.dtype(cfg.grad_accum_dtype)
+def make_train_step(cfg: ModelConfig, shape: ShapeConfig):
+    """Gradient-accumulated train step: (params, opt_state, batch) -> ...
+
+    Gradients are summed over the microbatches in float32."""
     model = build_model(cfg)
-    opt = make_optimizer(cfg)
+    opt = make_optimizer()
     G = max(shape.microbatches, 1)
 
     def loss_fn(params, mb):
@@ -86,7 +71,7 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig, *, accum_dtype=None):
     def train_step(params, opt_state, batch):
         if G == 1:
             (_, ce), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-            gsum = jax.tree_util.tree_map(lambda g: g.astype(accum_dtype), grads)
+            gsum = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
             ce_sum = ce
         else:
             mbs = jax.tree_util.tree_map(
@@ -97,12 +82,12 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig, *, accum_dtype=None):
                 gacc, lacc = carry
                 (_, ce), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
                 gacc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(accum_dtype), gacc, grads
+                    lambda a, g: a + g.astype(jnp.float32), gacc, grads
                 )
                 return (gacc, lacc + ce), None
 
             zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, accum_dtype), params
+                lambda p: jnp.zeros(p.shape, jnp.float32), params
             )
             (gsum, ce_sum), _ = maybe_scan(micro, (zeros, jnp.zeros((), jnp.float32)), mbs)
 
@@ -154,26 +139,20 @@ def make_distill_step(
 
     Student CE on its own labels + temperature-KL against the discovered
     teacher's logits (teacher params frozen).  Teacher and student may be
-    different architectures — only the vocab must match (DESIGN §5).
+    different architectures — only the vocab must match.
     """
     assert student_cfg.vocab_size == teacher_cfg.vocab_size
     student = build_model(student_cfg)
     teacher = build_model(teacher_cfg)
-    opt = make_optimizer(student_cfg)
+    opt = make_optimizer()
     G = max(shape.microbatches, 1)
 
     def loss_fn(params, teacher_logits, mb):
         logits, aux = student.forward(params, mb)
-        if student_cfg.kd_chunk:
-            loss, parts = distillation_loss_chunked(
-                logits, teacher_logits, mb["labels"], alpha=alpha,
-                temperature=temperature, chunk=student_cfg.kd_chunk,
-            )
-        else:
-            loss, parts = distillation_loss(
-                logits, teacher_logits, mb["labels"], alpha=alpha,
-                temperature=temperature,
-            )
+        loss, parts = distillation_loss(
+            logits, teacher_logits, mb["labels"], alpha=alpha,
+            temperature=temperature,
+        )
         if student_cfg.is_moe:
             loss = loss + student_cfg.router_aux_weight * aux["moe_aux"]
         return loss, parts["ce"]
@@ -206,7 +185,7 @@ def make_distill_step(
 
 
 # ---------------------------------------------------------------------------
-# Abstract, sharding-annotated input specs (the dry-run's stand-ins)
+# Abstract, sharding-annotated input specs (lowered without device memory)
 # ---------------------------------------------------------------------------
 
 
@@ -228,13 +207,12 @@ def abstract_sharded_params(model, cfg: ModelConfig, mesh: Mesh):
 
 
 def abstract_opt_state(model, cfg: ModelConfig, mesh: Mesh):
-    """AdamW state stand-ins; moments ZeRO-sharded over the data axis."""
+    """AdamW state stand-ins; float32 moments ZeRO-sharded over the data axis."""
     specs = model.param_specs()
     pspecs = param_pspecs_even(specs, cfg.family, mesh)
-    mdt = jnp.dtype(cfg.opt_moment_dtype)
 
     def moment(s: ParamSpec, ps: P):
-        return _sds(s.shape, mdt, mesh, opt_state_pspec(ps, s.shape, mesh))
+        return _sds(s.shape, jnp.float32, mesh, opt_state_pspec(ps, s.shape, mesh))
 
     def is_spec(x):
         return isinstance(x, ParamSpec)

@@ -1,8 +1,7 @@
 """End-to-end training driver.
 
-Runs the same ``train_step`` the dry-run lowers, on real devices (the CPU
-smoke path uses reduced configs; on a TPU slice the production configs and
-``make_production_mesh`` apply unchanged).
+Runs ``launch.steps.make_train_step`` on the local device (the CPU smoke
+path uses reduced configs).
 
 Example (CPU, ~100M model, a few hundred steps):
   PYTHONPATH=src python -m repro.launch.train --arch qwen2_1_5b --smoke \
@@ -14,7 +13,6 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint

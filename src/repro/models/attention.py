@@ -27,28 +27,10 @@ from repro.common import spans
 from repro.common.types import AXIS_EMBED, AXIS_HEADS, AXIS_KV, ParamSpec
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope
-from repro.sharding.rules import constrain
 
 NEG_INF = -1e30
 # rows per query block of causal self-attention (``causal_blocks``)
 CAUSAL_BLOCK = 512
-
-
-def _constrain_gqa(qg, k, v):
-    """Pin the KV-head dim to the model axis (GSPMD pads KV<model).
-
-    Without this, GSPMD splits the *head_dim contraction* across the spare
-    model-axis factor and partial-sums full (S,T) score tensors — measured
-    60 GB all-reduces per layer on deepseek prefill_32k.  Padding the KV
-    dim duplicates some QK^T compute instead, which is ~8× cheaper than
-    the collective at these shapes.
-
-    qg: (B,S,KV,G,hd); k, v: (B,T,KV,hd).
-    """
-    qg = constrain(qg, "data", None, "model", None, None)
-    k = constrain(k, "data", None, "model", None)
-    v = constrain(v, "data", None, "model", None)
-    return qg, k, v
 
 
 def attention_spec(cfg: ModelConfig, *, cross: bool = False):
@@ -82,14 +64,12 @@ def _project_qkv(params, cfg: ModelConfig, x, kv_input=None):
     return q, k, v
 
 
-def _gqa_scores(q, k, v=None, *, pin=False):
+def _gqa_scores(q, k):
     """q: (B,S,H,hd), k: (B,T,KV,hd) -> scores (B,KV,G,S,T)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
     qg = q.reshape(B, S, KV, G, hd)
-    if pin and v is not None:
-        qg, k, v = _constrain_gqa(qg, k, v)
     return jnp.einsum("bskgd,btkd->bkgst", qg, k) / jnp.sqrt(hd).astype(q.dtype)
 
 
@@ -105,62 +85,6 @@ def _softmax(scores):
     return jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
 
 
-def _attend_chunked(q, k, v, positions, *, causal, window, chunk, out_dtype,
-                    pin=False):
-    """Flash-style online-softmax attention in pure jnp (lax.scan over KV
-    chunks) — never materializes the (S,T) score matrix.  This is the
-    TPU-dry-run / CPU mirror of kernels/flash_attention.py, used for long
-    sequences where dense scores dominate peak memory.  q roped (B,S,H,hd);
-    k, v roped (B,T,KV,hd)."""
-    from repro.common.scan import maybe_scan
-
-    B, S, H, hd = q.shape
-    T, KV = k.shape[1], k.shape[2]
-    G = H // KV
-    nc = T // chunk
-    assert T % chunk == 0, (T, chunk)
-    qg = q.reshape(B, S, KV, G, hd).astype(jnp.float32)
-    if pin:
-        qg, k, v = _constrain_gqa(qg, k, v)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-
-    kc = jnp.moveaxis(k.reshape(B, nc, chunk, KV, hd), 1, 0)
-    vc = jnp.moveaxis(v.reshape(B, nc, chunk, KV, hd), 1, 0)
-    pc = jnp.moveaxis(positions.reshape(B, nc, chunk), 1, 0)
-    i = positions[:, None, None, :, None]  # query positions (B,1,1,S,1)
-
-    def body(carry, inp):
-        m, lsum, acc = carry
-        k_i, v_i, pos_i = inp
-        s = jnp.einsum("bskgd,bckd->bkgsc", qg, k_i.astype(jnp.float32)) * scale
-        j = pos_i[:, None, None, None, :]
-        mask = jnp.ones(s.shape[-2:], bool)[None, None, None]
-        if causal:
-            mask = mask & (j <= i)
-        if window is not None:
-            mask = mask & (i - j < window)
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alive = m_new > NEG_INF / 2
-        p = jnp.where(alive[..., None], jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.where(alive, jnp.exp(m - m_new), 1.0)
-        lsum = lsum * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bkgsc,bckd->bkgsd", p, v_i.astype(jnp.float32)
-        )
-        return (m_new, lsum, acc), None
-
-    init = (
-        jnp.full((B, KV, G, S), NEG_INF, jnp.float32),
-        jnp.zeros((B, KV, G, S), jnp.float32),
-        jnp.zeros((B, KV, G, S, hd), jnp.float32),
-    )
-    (m, lsum, acc), _ = maybe_scan(body, init, (kc, vc, pc))
-    safe = jnp.where(lsum > 0, lsum, 1.0)
-    out = (acc / safe[..., None]).astype(out_dtype)  # (B,KV,G,S,hd)
-    return jnp.moveaxis(out, 3, 1).reshape(B, S, KV * G * hd)
-
-
 def causal_blocks(S: int) -> int:
     """The query blocks causal self-attention over ``S`` rows splits into:
     ``S // CAUSAL_BLOCK`` where that is two or more and exact, else 1."""
@@ -168,11 +92,11 @@ def causal_blocks(S: int) -> int:
     return n if n >= 2 and rest == 0 else 1
 
 
-def _attend_dense(q, k, v, q_pos, k_pos, *, causal, window, out_dtype, pin):
+def _attend_dense(q, k, v, q_pos, k_pos, *, causal, window, out_dtype):
     """Masked softmax attention over all of ``k``: scores in q's dtype, an
     f32 softmax, weights in ``out_dtype`` into the P·V einsum.  q roped
     (B,S,H,hd), positions (B,S); k, v roped (B,T,KV,hd), positions (B,T)."""
-    scores = _gqa_scores(q, k, v, pin=pin)  # (B,KV,G,S,T)
+    scores = _gqa_scores(q, k)  # (B,KV,G,S,T)
     i = q_pos[:, None, None, :, None]
     j = k_pos[:, None, None, None, :]
     mask = jnp.ones(scores.shape[-2:], dtype=bool)[None, None, None]
@@ -185,7 +109,7 @@ def _attend_dense(q, k, v, q_pos, k_pos, *, causal, window, out_dtype, pin):
     return _gqa_out(weights, v, out_dtype)
 
 
-def _attend_causal_blocks(q, k, v, positions, n, *, window, out_dtype, pin):
+def _attend_causal_blocks(q, k, v, positions, n, *, window, out_dtype):
     """Causal attention as ``n`` query blocks, each over its key prefix.
 
     Block ``i`` scores its rows against keys ``[0, (i+1)·S/n)`` only, with
@@ -201,7 +125,7 @@ def _attend_causal_blocks(q, k, v, positions, n, *, window, out_dtype, pin):
         outs.append(_attend_dense(
             q[:, lo:hi], k[:, :hi], v[:, :hi], positions[:, lo:hi],
             positions[:, :hi], causal=True, window=window,
-            out_dtype=out_dtype, pin=pin))
+            out_dtype=out_dtype))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -217,30 +141,23 @@ def attend_full(
     """Self-attention over a contiguous sequence (train / prefill).
 
     Causal attention over two or more ``CAUSAL_BLOCK``-row blocks computes
-    only the blocks on and below the diagonal (``causal_blocks``).  Each
-    trace notes its path and the share of the S × S scores it computes in
+    only the blocks on and below the diagonal (``causal_blocks``); any
+    other attention is one dense pass (``_attend_dense``).  Each trace
+    notes its path and the share of the S × S scores it computes in
     ``spans.attention_paths()``.
     """
     q, k, v = _project_qkv(params, cfg, x)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_fraction)
     k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_fraction)
-    S = q.shape[1]
-    if cfg.attn_chunk and S > cfg.attn_chunk and S % cfg.attn_chunk == 0:
-        spans.note_attention("chunked", 1.0)
-        out = _attend_chunked(q, k, v, positions, causal=causal, window=window,
-                              chunk=cfg.attn_chunk, out_dtype=x.dtype,
-                              pin=cfg.attn_pin_kv)
-        return jnp.einsum("bsh,hd->bsd", out, params["wo"]), (k, v)
-    n = causal_blocks(S) if causal else 1
+    n = causal_blocks(q.shape[1]) if causal else 1
     if n > 1:
         spans.note_attention("causal_blocks", (n + 1) / (2 * n))
         out = _attend_causal_blocks(q, k, v, positions, n, window=window,
-                                    out_dtype=x.dtype, pin=cfg.attn_pin_kv)
+                                    out_dtype=x.dtype)
     else:
         spans.note_attention("dense", 1.0)
         out = _attend_dense(q, k, v, positions, positions, causal=causal,
-                            window=window, out_dtype=x.dtype,
-                            pin=cfg.attn_pin_kv)
+                            window=window, out_dtype=x.dtype)
     return jnp.einsum("bsh,hd->bsd", out, params["wo"]), (k, v)
 
 

@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,18 +58,6 @@ class ModelConfig:
     num_patches: int = 0  # vision frontend stub output length (0 = text only)
     # training
     dtype: str = "bfloat16"
-    remat: bool = True
-    scan_layers: bool = True
-    # perf knobs (§Perf hillclimb; defaults are the paper-faithful baseline)
-    seq_parallel: bool = False  # shard the residual stream's seq dim over model
-    grad_accum_dtype: str = "float32"  # bf16 halves accumulator memory
-    attn_chunk: int = 0  # >0: flash-style chunked attention for S > attn_chunk
-    moe_group_size: int = 512  # dispatch group size (bytes/flops ∝ group size)
-    moe_impl: str = "gspmd"  # gspmd (grouped one-hot) | shard_map (all-to-all)
-    moe_pin_layouts: bool = False  # constrain() the dispatch/expert layouts
-    attn_pin_kv: bool = False  # pin KV-head dim to model axis in attention
-    opt_moment_dtype: str = "float32"  # bf16 halves optimizer-state memory
-    kd_chunk: int = 0  # >0: vocab-chunked online distillation loss
     # block variant for xlstm: "xlstm" uses mLSTM/sLSTM stack instead of attn
     block_type: str = "attention"  # attention | xlstm
 

@@ -26,7 +26,6 @@ from repro.models.layers import (
     unembed,
 )
 from repro.models.transformer import Model
-from repro.sharding.rules import constrain
 
 
 def _enc_block_spec(cfg: ModelConfig):
@@ -75,12 +74,9 @@ def encode(params, cfg: ModelConfig, frames):
         )
         x = x + h
         x = x + mlp_apply(cfg.mlp_type, bp["mlp"], rmsnorm(bp["ln2"], x))
-        if cfg.seq_parallel:
-            x = constrain(x, "data", "model", None)
         return x, {}
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
+    body = jax.checkpoint(body)
     x, _ = maybe_scan(body, x, params["encoder"]["blocks"])
     return rmsnorm(params["encoder"]["final_norm"], x)
 
@@ -103,13 +99,10 @@ def encdec_forward(
         h, xkv = attn.attend_cross(bp["xattn"], cfg, rmsnorm(bp["lnx"], x), memory)
         x = x + h
         x = x + mlp_apply(cfg.mlp_type, bp["mlp"], rmsnorm(bp["ln2"], x))
-        if cfg.seq_parallel:
-            x = constrain(x, "data", "model", None)
         entry = {"kv": kv, "xkv": xkv} if collect_cache else {}
         return x, entry
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
+    body = jax.checkpoint(body)
     x, entries = maybe_scan(body, x, dec["blocks"])
     if last_logit_only:
         x = x[:, -1:]

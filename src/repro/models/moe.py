@@ -1,14 +1,9 @@
 """Mixture-of-Experts layer.
 
-Two execution paths:
-
-* ``dense``  — grouped one-hot dispatch einsum.  Works on any device count,
-  used for CPU smoke tests and as the GSPMD baseline (groups shard over the
-  data axis, experts over the model axis).
-* ``expert_parallel`` — shard_map + ``jax.lax.all_to_all`` token routing,
-  the TPU-native expert-parallel schedule (see repro.sharding.expert_parallel).
-
-Both share the same parameters and router, and agree numerically (tested).
+``moe_apply`` runs the grouped one-hot dispatch einsum
+(``moe_apply_dense``), which works on any device count: groups shard over
+the data axis, experts over the model axis.  ``moe_apply_onehot`` is its
+ungrouped test oracle.
 """
 from __future__ import annotations
 
@@ -24,7 +19,9 @@ from repro.common.types import (
     ParamSpec,
 )
 from repro.models.config import ModelConfig
-from repro.sharding.rules import constrain
+
+# tokens per dispatch group (``moe_apply_dense``)
+MOE_GROUP_SIZE = 512
 
 
 def moe_spec(cfg: ModelConfig):
@@ -112,20 +109,9 @@ def _shared_expert(params, xf, y):
 
 
 def moe_apply(params, cfg: ModelConfig, x, *, capacity_factor: float = 1.25):
-    """Config-selected MoE execution path."""
-    if cfg.moe_impl == "shard_map":
-        try:
-            am = jax.sharding.get_abstract_mesh()
-        except Exception:
-            am = None
-        if am is not None and "data" in tuple(am.axis_names):
-            from repro.sharding.expert_parallel import moe_apply_expert_parallel
-
-            return moe_apply_expert_parallel(
-                params, cfg, x, mesh=am, capacity_factor=capacity_factor
-            )
+    """The model's MoE layer: grouped dispatch in groups of ``MOE_GROUP_SIZE``."""
     return moe_apply_dense(params, cfg, x, capacity_factor=capacity_factor,
-                           group_size=cfg.moe_group_size)
+                           group_size=MOE_GROUP_SIZE)
 
 
 def moe_apply_dense(
@@ -134,7 +120,7 @@ def moe_apply_dense(
     x,
     *,
     capacity_factor: float = 1.25,
-    group_size: int = 512,
+    group_size: int = MOE_GROUP_SIZE,
 ):
     """Grouped one-hot dispatch MoE — the production (GSPMD) path.
 
@@ -148,9 +134,8 @@ def moe_apply_dense(
         one-hots), and the (g,k,E)×(g,k,C) einsum contracts over k so the
         (g,k,E,C) outer product never materializes.
 
-    Sharding: groups ride the data axis; ``constrain`` reshards the (E,C,D)
-    expert buffer to expert-parallel layout (experts over data) around the
-    expert matmuls — GSPMD lowers the reshard to an all-to-all.
+    Sharding is left to GSPMD: the groups follow the batch's data axis and
+    the expert matmuls the expert weights' layout.
     """
     B, S, D = x.shape
     N = B * S
@@ -163,32 +148,19 @@ def moe_apply_dense(
     cap = -(-cap // 8) * 8  # multiple of 8 for TPU-friendly layouts
     cap = min(cap, g * k)
 
-    pin = (lambda t, *sp: constrain(t, *sp)) if cfg.moe_pin_layouts else (
-        lambda t, *sp: t)
     ranks = jax.vmap(lambda fe: _expert_ranks(fe, e))(topk_i.reshape(G, g * k))
     ranks = ranks.reshape(G, g, k)
     keep = ranks < cap
     slot = jnp.where(keep, ranks, cap)  # cap -> all-zero one-hot row (dropped)
     oh_e = jax.nn.one_hot(topk_i.reshape(G, g, k), e, dtype=x.dtype)
     oh_c = jax.nn.one_hot(slot, cap, dtype=x.dtype)
-    # Dispatch/combine live group-parallel (G over data) with the expert dim
-    # cut over model so no single device ever holds a full (g,E,C) slab.
     disp = jnp.einsum("gnke,gnkc->gnec", oh_e, oh_c)  # (G,g,E,C)
-    disp = pin(disp, "data", None, "model", None)
     wk = (topk_w.reshape(G, g, k) * keep).astype(x.dtype)
     comb = jnp.einsum("gnke,gnkc->gnec", oh_e, oh_c * wk[..., None])
-    comb = pin(comb, "data", None, "model", None)
 
-    xg = pin(xf.reshape(G, g, D), "data")
+    xg = xf.reshape(G, g, D)
     xe = jnp.einsum("gnec,gnd->gecd", disp, xg)  # (G,E,C,D), local per group
-    xe = pin(xe, "data", "model", None, None)
-    # expert-parallel phase: experts over data (all-to-all), embed over model
-    xe = pin(xe, None, "data", None, "model")
     ye = _expert_mlp(params, xe)
-    ye = pin(ye, None, "data", None, "model")
-    # back to group-parallel for the combine (all-to-all); partial-sum over
-    # the model-sharded expert dim turns into one all-reduce on y.
-    ye = pin(ye, "data", "model", None, None)
     y = jnp.einsum("gnec,gecd->gnd", comb, ye).reshape(N, D)
     if cfg.num_shared_experts:
         y = _shared_expert(params, xf, y)

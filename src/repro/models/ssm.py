@@ -3,7 +3,6 @@
 The GPU reference implementation leans on warp-level parallel scans; here the
 intra-chunk work is dense (Q×Q) matmuls that map onto the MXU, and only the
 O(S/chunk) inter-chunk state recurrence is a (log-depth associative) scan.
-See DESIGN.md §4 for the adaptation notes.
 
 Layouts:
   x_in    (B, S, D)

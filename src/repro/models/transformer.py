@@ -1,5 +1,5 @@
 """Decoder-only model assembly for dense / moe / vlm / hybrid / xlstm
-families, with scan-over-layers (stacked params), optional remat, KV-cache
+families, with scan-over-layers (stacked params), remat, KV-cache
 prefill and single-token decode.
 
 Layer organization: the stack is grouped into ``n_super`` scanned
@@ -14,7 +14,7 @@ Layer organization: the stack is grouped into ``n_super`` scanned
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +29,6 @@ from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models import xlstm as xlstm_lib
 from repro.models.config import ModelConfig
-from repro.sharding.rules import constrain
 from repro.models.layers import (
     embed,
     embedding_spec,
@@ -240,15 +239,9 @@ def decoder_forward(
         x, entry, losses = _super_apply(
             cfg, shared, bp, x, positions, window=window, collect=collect_cache
         )
-        if cfg.seq_parallel:
-            # Megatron-style sequence parallelism: the remat-saved residual
-            # carry is sharded (batch->data, seq->model); attention/MLP
-            # internals gather/scatter around it (GSPMD-inserted).
-            x = constrain(x, "data", "model", None)
         return (x, aux + losses["moe_aux"], z + losses["moe_z"]), entry
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
+    body = jax.checkpoint(body)
 
     (x, aux, z), kvs = maybe_scan(
         body, (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)), params["blocks"]

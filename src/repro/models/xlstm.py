@@ -4,7 +4,7 @@
 Follows arXiv:2405.04517.  The mLSTM parallel form is attention-shaped
 (Q·Kᵀ ⊙ gate-decay matrix) and maps onto the MXU; the sLSTM is inherently
 sequential (recurrent gate dependence on h_{t-1}) and uses lax.scan — the
-paper's own CUDA kernel is sequential per-head too (see DESIGN.md §4).
+paper's own CUDA kernel is sequential per-head too.
 """
 from __future__ import annotations
 

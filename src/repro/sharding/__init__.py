@@ -3,7 +3,6 @@ from repro.sharding.rules import (
     batch_pspec,
     batch_shardings,
     cache_pspecs,
-    constrain,
     evenly,
     opt_state_pspec,
     param_pspecs,
@@ -19,7 +18,6 @@ __all__ = [
     "batch_pspec",
     "batch_shardings",
     "cache_pspecs",
-    "constrain",
     "evenly",
     "opt_state_pspec",
     "param_pspecs",

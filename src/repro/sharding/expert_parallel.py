@@ -1,8 +1,9 @@
 """Explicit expert-parallel MoE schedule: shard_map + jax.lax.all_to_all.
 
 The GSPMD path (models/moe.py, grouped one-hot einsums) lets the compiler
-infer the reshards; this module writes the TPU-native schedule by hand —
-the §Perf beyond-paper alternative for collective-bound MoE pairs:
+infer the reshards; this module writes the TPU-native schedule by hand,
+an alternative for collective-bound MoE that no model path calls yet (only
+its tests in tests/test_moe.py do):
 
   per data-shard:  route locally → scatter to a local (E, C_loc, D) buffer
   all_to_all       split the expert dim across the data axis (each device

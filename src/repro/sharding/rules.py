@@ -1,14 +1,8 @@
 """Logical-axis → mesh-axis rules per architecture family.
 
 Every parameter declares *logical* axes (repro.common.types); this module
-maps them onto the production mesh axes:
-
-  single pod : (data=16, model=16)
-  multi-pod  : (pod=2, data=16, model=16)
-
-The ``pod`` axis is the continuum-tier axis (DESIGN §3): each pod hosts an
-independent learning party; nothing inside a compiled step crosses it
-except the batch dimension of data-parallel gradients.
+maps them onto the mesh axes ``data`` and ``model`` (``launch/mesh.py``).
+A mesh with a ``pod`` axis as well shards the batch over ``(pod, data)``.
 
 Rules are plain dicts ``logical_axis -> mesh axis (or tuple, or None)``.
 GSPMD handles non-divisible dims by padding, which we rely on for the
@@ -283,33 +277,3 @@ def party_shard_map(fn, mesh: Optional[Mesh], *, in_specs, out_specs):
         return fn
     return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-# ---------------------------------------------------------------------------
-# In-graph activation constraints (no-ops without a mesh context)
-# ---------------------------------------------------------------------------
-
-
-def _context_axes():
-    am = jax.sharding.get_abstract_mesh()
-    return tuple(am.axis_names) if am is not None else ()
-
-
-def constrain(x, *spec_entries):
-    """``with_sharding_constraint`` that degrades gracefully.
-
-    Entries name mesh axes (or tuples / None).  Axes absent from the
-    context mesh are dropped; with no mesh context (CPU smoke tests) this
-    is the identity.  Model code can therefore carry production sharding
-    annotations unconditionally.
-    """
-    axes = set(_context_axes())
-    if not axes:
-        return x
-    cleaned = []
-    for e in spec_entries:
-        names = e if isinstance(e, tuple) else ((e,) if e else ())
-        kept = tuple(n for n in names if n in axes)
-        cleaned.append(kept[0] if len(kept) == 1 else (kept or None))
-    cleaned += [None] * (len(x.shape) - len(cleaned))
-    return jax.lax.with_sharding_constraint(x, P(*cleaned))

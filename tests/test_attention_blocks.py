@@ -125,12 +125,12 @@ def test_blocked_gradients_equal_dense(wrt):
 
     def blocked(q, k, v):
         return jnp.sum(cot * attn._attend_causal_blocks(
-            q, k, v, pos, n, window=None, out_dtype=jnp.float32, pin=False))
+            q, k, v, pos, n, window=None, out_dtype=jnp.float32))
 
     def dense(q, k, v):
         return jnp.sum(cot * attn._attend_dense(
             q, k, v, pos, pos, causal=True, window=None,
-            out_dtype=jnp.float32, pin=False))
+            out_dtype=jnp.float32))
 
     got = jax.jit(jax.grad(blocked, argnums=wrt))(q, k, v)
     want = jax.jit(jax.grad(dense, argnums=wrt))(q, k, v)

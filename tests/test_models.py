@@ -5,12 +5,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.common import spans
 from repro.common.scan import maybe_scan, unroll_scans
 from repro.configs import get_smoke_config
 from repro.kernels.ref import ssd_scan_ref
+from repro.models import attention as attn
 from repro.models import build_model
 from repro.models import ssm as ssm_lib
-from repro.models.config import ModelConfig
 
 # minutes of model compilation on CPU; excluded from the fast tier-1 loop
 pytestmark = pytest.mark.slow
@@ -49,7 +50,8 @@ def test_maybe_scan_unrolled_equals_scanned():
 
 @pytest.mark.parametrize("arch", ["qwen2_1_5b", "zamba2_2_7b", "xlstm_1_3b"])
 def test_unrolled_forward_matches_scanned(arch):
-    """The roofline probe's unrolled lowering computes the same function."""
+    """A whole model lowered with its scans unrolled computes the same
+    function."""
     cfg = get_smoke_config(arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -150,26 +152,23 @@ def test_vlm_patches_fuse():
     assert not np.allclose(np.asarray(y1, np.float32), np.asarray(y2, np.float32))
 
 
-def test_chunked_attention_matches_dense():
-    """cfg.attn_chunk (flash-style jnp path) == dense scores path."""
-    base = get_smoke_config("qwen2_1_5b").replace(num_layers=2)
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window24"])
+def test_causal_blocks_forward_matches_dense(monkeypatch, window):
+    """The whole forward, through the layer scan and its remat, on four
+    16-row causal query blocks == one dense pass (``CAUSAL_BLOCK`` 65)."""
+    base = get_smoke_config("qwen2_1_5b").replace(num_layers=2,
+                                                  sliding_window=window)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 64), 0, base.vocab_size)
-    dense = build_model(base)
-    params = dense.init(jax.random.PRNGKey(0))
-    y1, _ = dense.forward(params, {"tokens": toks})
-    chunked = build_model(base.replace(attn_chunk=16))
-    y2, _ = chunked.forward(params, {"tokens": toks})
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y2, np.float32), rtol=3e-2, atol=3e-2)
-
-
-def test_chunked_attention_sliding_window_matches():
-    base = get_smoke_config("qwen2_1_5b").replace(num_layers=2, sliding_window=24)
-    toks = jax.random.randint(jax.random.PRNGKey(2), (1, 64), 0, base.vocab_size)
-    dense = build_model(base)
-    params = dense.init(jax.random.PRNGKey(0))
-    y1, _ = dense.forward(params, {"tokens": toks})
-    chunked = build_model(base.replace(attn_chunk=16))
-    y2, _ = chunked.forward(params, {"tokens": toks})
-    np.testing.assert_allclose(np.asarray(y1, np.float32),
-                               np.asarray(y2, np.float32), rtol=3e-2, atol=3e-2)
+    model = build_model(base)
+    params = model.init(jax.random.PRNGKey(0))
+    ys = {}
+    for block in (16, 65):
+        monkeypatch.setattr(attn, "CAUSAL_BLOCK", block)
+        before = spans.attention_paths()
+        ys[block], _ = model.forward(params, {"tokens": toks})
+        after = spans.attention_paths()
+        traced = {k for k, v in after.items() if v != before.get(k, 0)}
+        assert traced == ({("causal_blocks", 0.625)} if block == 16
+                          else {("dense", 1.0)})
+    np.testing.assert_allclose(np.asarray(ys[65], np.float32),
+                               np.asarray(ys[16], np.float32), rtol=3e-2, atol=3e-2)

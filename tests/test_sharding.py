@@ -1,8 +1,12 @@
 """Sharding rules: logical-axis mapping, divisibility guards, cache
-heuristics, and 1-device lowering of the dry-run step machinery."""
+heuristics, and 1-device lowering of the sharded step machinery."""
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
@@ -92,7 +96,7 @@ def test_cache_pspec_heuristics():
 @pytest.mark.parametrize("kind", ["train", "prefill", "decode"])
 def test_host_mesh_lowering(arch, kind):
     """input_specs + step lowering works on the 1-device host mesh for the
-    reduced configs — validates the whole dry-run path without 512 devices."""
+    reduced configs: the sharded lowering a multi-device mesh starts from."""
     mesh = make_host_mesh()
     cfg = get_smoke_config(arch)
     shape = ShapeConfig("t", 64, 4, kind, microbatches=2 if kind == "train" else 1)
@@ -100,6 +104,53 @@ def test_host_mesh_lowering(arch, kind):
     lowered = jax.jit(step).lower(*args)
     compiled = lowered.compile()
     assert cost_analysis_dict(compiled).get("flops", 0) > 0
+
+
+def test_train_state_keeps_float32_accumulators():
+    """A bf16 model keeps float32 AdamW moments and sums its microbatch
+    gradients in float32."""
+    mesh = make_host_mesh()
+    cfg = get_smoke_config("qwen2_1_5b")
+    assert cfg.dtype == "bfloat16"
+    shape = ShapeConfig("t", 32, 4, "train", microbatches=2)
+    step, (params, opt_state, batch) = input_specs(cfg, shape, mesh)
+    assert {p.dtype for p in jax.tree_util.tree_leaves(params)} == {jnp.dtype(jnp.bfloat16)}
+    moments = jax.tree_util.tree_leaves((opt_state["mu"], opt_state["nu"]))
+    assert len(moments) == 2 * len(jax.tree_util.tree_leaves(params))
+    assert {m.dtype for m in moments} == {jnp.dtype(jnp.float32)}
+    # the microbatch scan carries the gradient sum (one leaf per parameter)
+    # and the loss sum
+    jaxpr = jax.make_jaxpr(step)(params, opt_state, batch).jaxpr
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    lo = scan.params["num_consts"]
+    carry = scan.invars[lo:lo + scan.params["num_carry"]]
+    assert len(carry) == len(jax.tree_util.tree_leaves(params)) + 1
+    assert {v.aval.dtype for v in carry} == {jnp.dtype(jnp.float32)}
+
+
+def test_importing_the_program_leaves_xla_flags_alone():
+    """No module of the model, kernel, sharding or launch packages sets
+    ``XLA_FLAGS`` (a forced host device count) when it is imported."""
+    code = """
+import importlib, json, os, pkgutil
+import repro.kernels, repro.launch, repro.models, repro.sharding
+before = os.environ.get("XLA_FLAGS")
+names = []
+for pkg in (repro.launch, repro.models, repro.kernels, repro.sharding):
+    for m in pkgutil.walk_packages(pkg.__path__, pkg.__name__ + "."):
+        importlib.import_module(m.name)
+        names.append(m.name)
+print(json.dumps([len(names), before, os.environ.get("XLA_FLAGS")]))
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + [p for p in [env.get("PYTHONPATH")] if p])
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout
+    n, before, after = json.loads(out.strip().splitlines()[-1])
+    assert n >= 20
+    assert before == after == os.environ.get("XLA_FLAGS")
 
 
 def test_host_mesh_lowering_long_context():
@@ -132,7 +183,6 @@ def test_distill_step_trains_student():
     """One distill step moves the student toward the teacher distribution."""
     import jax.numpy as jnp
     from repro.launch.steps import make_distill_step
-    from repro.models import build_model
 
     s_cfg = get_smoke_config("qwen2_1_5b")
     t_cfg = get_smoke_config("qwen2_1_5b")
